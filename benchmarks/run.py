@@ -17,6 +17,8 @@ def main() -> None:
                     help="skip the measured (wall-clock) benches")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from . import codec_bench as C
     from . import energy_front as E
     from . import kway_runtime as K

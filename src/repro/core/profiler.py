@@ -77,8 +77,6 @@ def costs_from_hlo(
     for name, fn, x in zip(block_names, block_fns, example_inputs):
         compiled = jax.jit(fn).lower(x).compile()
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):   # jax<=0.4: one dict per device
-            ca = ca[0] if ca else {}
         flops = float(ca.get("flops", 0.0))
         nbytes = float(ca.get("bytes accessed", 0.0))
         table.set(device.name, name, device.compute_time(flops, nbytes))

@@ -104,8 +104,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
             t_compile = time.time() - t0 - t_lower
 
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):   # jax<=0.4: one dict per device
-            ca = ca[0] if ca else {}
         ma = compiled.memory_analysis()
         hlo = compiled.as_text()
         coll = parse_collectives(hlo, pod_size)

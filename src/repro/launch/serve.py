@@ -27,7 +27,9 @@ def main(argv=None) -> int:
     from ..models import lm
     from ..models.common import DTYPES, InitBuilder
     from ..runtime.steps import make_decode_step, make_prefill_step
+    from .compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=list(configs.ARCH_NAMES))
     ap.add_argument("--reduced", action="store_true")

@@ -39,12 +39,14 @@ def main(argv=None) -> int:
     from ..models import lm
     from ..models.common import DTYPES, InitBuilder
     from ..optim import CompressionConfig, OptConfig, cosine_schedule
-    from ..runtime.pipeline import (PipelineConfig, make_pipeline_train_step,
-                                    repack_params, unpack_params)
+    from ..runtime.pipeline import (PipelineConfig, init_pipeline_state,
+                                    make_pipeline_train_step)
     from ..runtime.steps import init_train_state, make_train_step
     from ..sharding.api import use_mesh_context
+    from .compile_cache import enable_compile_cache
     from .mesh import make_host_mesh
 
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=list(configs.ARCH_NAMES))
     ap.add_argument("--reduced", action="store_true")
@@ -103,19 +105,14 @@ def main(argv=None) -> int:
     if ctx_mgr is not None:
         ctx_mgr.__enter__()
     try:
-        state = init_train_state(cfg, jax.random.PRNGKey(args.seed), opt,
-                                 comp)
+        key = jax.random.PRNGKey(args.seed)
         if pcfg is not None:
-            lk = "dec_layers" if cfg.family == "encdec" else "layers"
-            state["params"] = {**state["params"],
-                               lk: repack_params(state["params"][lk], pcfg,
-                                                 cfg.n_layers)}
-            zeros = lambda p: jnp.zeros(p.shape, jnp.float32)
-            state["opt"] = {**state["opt"],
-                            "m": jax.tree.map(zeros, state["params"]),
-                            "v": jax.tree.map(zeros, state["params"])}
+            params = lm.build_params(cfg, InitBuilder(key, DTYPES[cfg.dtype]))
+            state = init_pipeline_state(cfg, pcfg, params, mesh)
+            del params
             step_fn = make_pipeline_train_step(cfg, pcfg, opt, mesh)
         else:
+            state = init_train_state(cfg, key, opt, comp)
             step_fn = make_train_step(cfg, opt, comp)
         step_fn = jax.jit(step_fn, donate_argnums=(0,))
 

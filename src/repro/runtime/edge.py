@@ -100,36 +100,40 @@ class Worker:
         # remainder is a sleep, so replicated stages genuinely overlap
         # even on a single-core host.
         self.pace_s = pace_s
-        sub = params[lo:hi]
-        layers = [layer for (_, layer) in model.blocks[lo:hi]]
-        if backend == "lightweight":
-            def fused(x, _layers=tuple(layers), _sub=tuple(sub)):
-                for l, p in zip(_layers, _sub):
-                    x = l.apply(p, x)
-                return x
-            self._fns = [jax.jit(fused)]
-        else:
+        # the stage's weights live on the device it runs on and enter
+        # the program as arguments, not as constants baked into it
+        self.device = jax.devices()[0]
+        self.params = jax.device_put(tuple(params[lo:hi]), self.device)
+        layers = tuple(layer for (_, layer) in model.blocks[lo:hi])
+
+        def fused(ps, x):
+            for l, p in zip(layers, ps):
+                x = l.apply(p, x)
+            return x
+        # ``fn(params, x)``: the whole stage as one program
+        self.fn = jax.jit(fused)
+        if backend == "rpc":
             # module-granularity dispatch, one jitted call per block
-            self._fns = [jax.jit(lambda x, l=layer, p=p: l.apply(p, x))
-                         for layer, p in zip(layers, sub)]
+            self._calls = [(jax.jit(lambda p, x, l=l: l.apply(p, x)), p)
+                           for l, p in zip(layers, self.params)]
+        else:
+            self._calls = [(self.fn, self.params)]
 
     def warmup(self, x):
-        for fn in self._fns:
-            x = fn(x)
+        for fn, p in self._calls:
+            x = fn(p, x)
         jax.block_until_ready(x)
         return x
 
     def run(self, x):
         t0 = time.perf_counter()
         c0 = self._cpu_clock()
-        if self.backend == "rpc":
-            for fn in self._fns:
+        for fn, p in self._calls:
+            if self.backend == "rpc":
                 # serialize/deserialize at every module-call boundary
                 x = _Serializer.loads(_Serializer.dumps(x))
                 time.sleep(RPC_PER_CALL_OVERHEAD_S)
-                x = fn(x)
-        else:
-            x = self._fns[0](x)
+            x = fn(p, x)
         x = jax.block_until_ready(x)
         if self.pace_s > 0.0:
             rem = self.pace_s - (time.perf_counter() - t0)
@@ -284,6 +288,10 @@ class _ThreadEngine:
         """Flat stage-major worker list (replica-free pipelines see the
         historical one-worker-per-stage shape)."""
         return [w for ws in self.stage_workers for w in ws]
+
+    @property
+    def worker_platforms(self) -> list[str]:
+        return [w.device.platform for w in self.workers]
 
     def _build_workers(self, reuse: Sequence[Worker] = ()) -> None:
         """Instantiate stage workers, reusing any existing worker whose
@@ -552,6 +560,7 @@ class _ProcessEngine:
         self._meters = [HopMeter(l) for l in pipe.links]
         self._stats = [StageStats() for _ in range(k)]
         self._procs: list = []
+        self.worker_platforms: list[str] = []  # as each worker reported
         self._ctrls: list = []
         self._ctrl_stage: list[int] = []      # worker w -> its logical stage
         self._proc_slot: list[tuple[int, int]] = []   # worker w -> (stage, lane)
@@ -691,11 +700,13 @@ class _ProcessEngine:
                     pair[0].close()
                 if j != k:
                     pair[1].close()
+        self.worker_platforms.clear()
         for w in range(len(self._procs)):
             msg = self._ctrl_recv(w)
             if msg[0] != "ready":
                 raise TransportError(
                     f"worker {self._ctrl_stage[w]} failed to start: {msg}")
+            self.worker_platforms.append(msg[2])
 
     # ------------------------------------------------------------------ #
     @property
@@ -1309,6 +1320,13 @@ class EdgePipeline:
             raise AttributeError("workers live in their own processes under "
                                  f"transport={self.transport!r}")
         return self._engine.workers
+
+    @property
+    def worker_platforms(self) -> list[str]:
+        """The JAX platform each worker computes on, stage-major (one
+        entry per replica): the parent's device for thread stages, the
+        platform a process worker reported when it came up."""
+        return list(self._engine.worker_platforms)
 
     @property
     def p(self) -> int:

@@ -36,26 +36,12 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..models import lm
 from ..models.common import Builder, cross_entropy, embed_lookup, lm_logits
 from ..sharding.api import shard
 from ..optim import OptConfig, apply_gradients
-
-
-def _shard_map(f, mesh, in_specs, out_specs, axis_names):
-    """Partial-manual shard_map across jax versions: jax>=0.6 spells it
-    jax.shard_map(axis_names=..., check_vma=...); older releases have
-    jax.experimental.shard_map with auto=<complement> / check_rep=."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=set(axis_names),
-                             check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm
-    auto = frozenset(mesh.axis_names) - set(axis_names)
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=False, auto=auto)
 
 
 # --------------------------------------------------------------------------- #
@@ -149,6 +135,30 @@ def repack_params(stacked_layers, pcfg: PipelineConfig, n_layers: int):
             out = out.at[s, :counts[s]].set(blk)
         return out
     return jax.tree.map(repack, stacked_layers)
+
+
+def init_pipeline_state(cfg, pcfg: PipelineConfig, params, mesh) -> dict:
+    """Canonical params → the pipelined train state, placed on ``mesh``:
+    layer stacks repacked to (K, l_max, ...) and sharded over ``pod``,
+    everything else laid out by the sharding rules, and the AdamW
+    moments created in place.  No device ever holds the whole state, so
+    a model whose optimizer state outgrows one chip still starts."""
+    from ..models.common import SpecBuilder
+    from ..optim import init_opt_state
+    from ..sharding.api import MeshContext
+    lk = "dec_layers" if cfg.family == "encdec" else "layers"
+    specs = build_pipeline_params(cfg, SpecBuilder(MeshContext(mesh)), pcfg)
+    placed = jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
+                          is_leaf=lambda s: isinstance(s, P))
+    rep = NamedSharding(mesh, P())
+
+    def make(params):
+        p = {**params, lk: repack_params(params[lk], pcfg, cfg.n_layers)}
+        return {"params": p, "opt": init_opt_state(p),
+                "step": jnp.zeros((), jnp.int32)}
+    out = {"params": placed,
+           "opt": {"m": placed, "v": placed, "count": rep}, "step": rep}
+    return jax.jit(make, out_shardings=out)(params)
 
 
 def unpack_params(pipeline_layers, pcfg: PipelineConfig, n_layers: int):
@@ -282,10 +292,10 @@ def make_pipeline_train_step(cfg, pcfg: PipelineConfig, opt: OptConfig,
             _, ys = jax.lax.scan(tick, buf0, jnp.arange(T))
             return ys[None]                       # (1, T, mb, S, D) per pod
 
-        ys = _shard_map(
+        ys = jax.shard_map(
             pipeline, mesh=mesh,
             in_specs=(P("pod"), P(), P(), P()), out_specs=P("pod"),
-            axis_names={"pod"},
+            axis_names={"pod"}, check_vma=False,
         )(layers, x_mb32, enc_mb32, shared32)
         # finished microbatches come off the last pod at ticks K-1 .. T-1
         out = ys[K - 1][K - 1:]                    # (M, mb, S, D)
@@ -360,9 +370,9 @@ def make_pipeline_prefill_step(cfg, pcfg: PipelineConfig, mesh,
             last = ys[K - 1]
             return jax.tree.map(lambda c: c[None], (last, cache))
 
-        last, cache = _shard_map(
+        last, cache = jax.shard_map(
             pipeline, mesh=mesh, in_specs=(P("pod"), P()),
-            out_specs=P("pod"), axis_names={"pod"},
+            out_specs=P("pod"), axis_names={"pod"}, check_vma=False,
         )(layers, x)
         h = lm.final_hidden(cfg, params, last[K - 1])
         logits = lm_logits(h[:, -1:], params["embed"], params.get("lm_head"))
@@ -408,9 +418,9 @@ def make_pipeline_decode_step(cfg, pcfg: PipelineConfig, mesh):
             (_, kv), ys = jax.lax.scan(tick, (x, kv), jnp.arange(K))
             return jax.tree.map(lambda c: c[None], (ys[K - 1], kv))
 
-        last, kv = _shard_map(
+        last, kv = jax.shard_map(
             pipeline, mesh=mesh, in_specs=(P("pod"), P("pod"), P()),
-            out_specs=P("pod"), axis_names={"pod"},
+            out_specs=P("pod"), axis_names={"pod"}, check_vma=False,
         )(layers, kv, x)
         h = lm.final_hidden(cfg, params, last[K - 1])
         logits = lm_logits(h, params["embed"], params.get("lm_head"))

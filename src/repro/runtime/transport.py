@@ -297,13 +297,14 @@ def as_jax(x):
     dlpack where available — the device put aliases host memory on the
     CPU backend instead of copying.  Safe under the zero-copy lease
     because the worker loop calls ``block_until_ready`` before the next
-    recv() releases the buffer.  Falls back to handing jax the ndarray
-    (one host copy at dispatch)."""
+    recv() releases the buffer.  A buffer DLPack cannot export (read-only,
+    or a dtype it has no code for, such as bfloat16) goes to jax as the
+    ndarray: one host copy at dispatch."""
     if isinstance(x, np.ndarray) and x.size:
+        import jax.dlpack
         try:
-            import jax.dlpack
             return jax.dlpack.from_dlpack(x)
-        except Exception:
+        except BufferError:
             return x
     return x
 
@@ -1535,6 +1536,23 @@ def _flush_stats(stage: int, worker, ingress: Channel):
             mem_pct, records)
 
 
+def _pin_host_platform() -> str:
+    """Put this spawned process's JAX on the host CPU before anything
+    touches a backend, and return the platform JAX reports.  An
+    accelerator belongs to one process at a time and the parent that
+    spawned this worker already holds it: left to itself, the child
+    would fail on the accelerator's lock or fall back to the CPU
+    without saying so."""
+    import jax
+    jax.config.update("jax_platforms", "cpu")
+    platform = jax.devices()[0].platform
+    if platform != "cpu":
+        raise TransportError(
+            f"worker JAX came up on {platform!r}: a backend was "
+            f"initialized before the worker pinned the host CPU")
+    return platform
+
+
 def _worker_main(spec: dict) -> None:
     """One pipeline stage as an OS process: recv → compute → send."""
     from .edge import Worker
@@ -1554,8 +1572,9 @@ def _worker_main(spec: dict) -> None:
                       pace_s=spec.get("pace_s", 0.0))
 
     try:
+        platform = _pin_host_platform()
         worker = build(bounds)
-        ctrl.send(("ready", stage))
+        ctrl.send(("ready", stage, platform))
         # flush-cancel skip window: the parent's out-of-band ("cancel",)
         # ctrl message overtakes the in-band stream, so batches already
         # queued ahead of the CANCEL fence skip compute and travel as
@@ -1638,7 +1657,8 @@ def _sink_main(spec: dict) -> None:
     chan: Channel = spec["chan"]
     ctrl = spec["ctrl"]
     try:
-        ctrl.send(("ready",))
+        # coded frames decode through JAX kernels: on the host CPU
+        ctrl.send(("ready", _pin_host_platform()))
         while True:
             try:
                 kind, _ = chan.recv(timeout=0.25)

@@ -109,12 +109,7 @@ class use_mesh_context:
     def __enter__(self):
         if self.mesh is not None:
             set_context(MeshContext(self.mesh))
-            # jax >= 0.6 exposes jax.set_mesh / jax.sharding.use_mesh;
-            # on older releases the Mesh object itself is the context
-            # manager that installs the global mesh.
-            set_mesh = (getattr(jax, "set_mesh", None)
-                        or getattr(jax.sharding, "use_mesh", None))
-            self._jax_ctx = set_mesh(self.mesh) if set_mesh else self.mesh
+            self._jax_ctx = jax.set_mesh(self.mesh)
             self._jax_ctx.__enter__()
         return get_context()
 

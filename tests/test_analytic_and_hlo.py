@@ -31,8 +31,6 @@ def test_analytic_fwd_flops_vs_xla_unrolled():
 
     comp = jax.jit(fwd).lower(params, tokens).compile()
     ca = comp.cost_analysis()
-    if isinstance(ca, (list, tuple)):   # jax<=0.4: one dict per device
-        ca = ca[0]
     xla_flops = float(ca["flops"])
     ctx = (S + 1) / 2  # S <= chunk → exact causal masking in one block,
     # but the single-block path COMPUTES the full S×S scores:

@@ -28,16 +28,6 @@ if jax.device_count() == 1:
                             capture_output=True, text=True, timeout=900)
         assert cp.returncode == 0, cp.stdout + "\n" + cp.stderr
 
-    # partial-manual shard_map (manual over 'pod', auto elsewhere) only
-    # lowers on jax >= 0.6; jax 0.4's SPMD partitioner rejects the
-    # axis_index → PartitionId op inside an auto-axes shard_map
-    _needs_new_shard_map = pytest.mark.xfail(
-        not hasattr(jax, "shard_map"),
-        reason="partial-manual shard_map (axis_names=) requires jax>=0.6; "
-               "this jax lowers axis_index to an unpartitionable PartitionId",
-        strict=False)
-
-    @_needs_new_shard_map
     def test_pipeline_train_matches_plain():
         _run_sub("""
 import jax, jax.numpy as jnp
@@ -45,11 +35,13 @@ import repro.configs as configs
 from repro.models import lm
 from repro.models.common import InitBuilder, cross_entropy
 from repro.data.pipeline import SyntheticLM, DataConfig
-from repro.runtime.pipeline import PipelineConfig, repack_params, make_pipeline_train_step
+from repro.runtime.pipeline import (PipelineConfig, init_pipeline_state,
+                                    repack_params, make_pipeline_train_step)
 from repro.optim import OptConfig, init_opt_state
+from repro.launch.mesh import make_host_mesh
 from repro.sharding.api import use_mesh_context
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_host_mesh(2, 2, 2)
 for name in ["qwen3-1.7b", "zamba2-7b", "qwen3-moe-30b-a3b", "whisper-small", "falcon-mamba-7b"]:
     cfg = configs.reduced(name)
     params = lm.build_params(cfg, InitBuilder(jax.random.PRNGKey(0), jnp.float32))
@@ -64,12 +56,19 @@ for name in ["qwen3-1.7b", "zamba2-7b", "qwen3-moe-30b-a3b", "whisper-small", "f
         state = {"params": pparams, "opt": init_opt_state(pparams), "step": jnp.int32(0)}
         step = jax.jit(make_pipeline_train_step(cfg, pcfg, OptConfig(lr=1e-3), mesh))
         state, m = step(state, batch)
+        # the launcher's initializer: same weights, placed on the mesh
+        placed = init_pipeline_state(cfg, pcfg, params, mesh)
+        same = jax.tree.map(jnp.array_equal, placed["params"], pparams)
+        assert all(map(bool, jax.tree.leaves(same))), name
+        leaf = jax.tree.leaves(placed["params"][key])[0]
+        assert leaf.sharding.spec[0] == "pod", (name, leaf.sharding)
+        _, m2 = step(placed, batch)
     diff = abs(float(m["ce"]) - ref_ce)
     assert diff < 5e-4, (name, diff)
+    assert abs(float(m2["ce"]) - ref_ce) < 5e-4, (name, float(m2["ce"]))
 print("OK")
 """)
 
-    @_needs_new_shard_map
     def test_pipeline_uneven_cuts_and_serving():
         _run_sub("""
 import jax, jax.numpy as jnp
@@ -79,9 +78,10 @@ from repro.models.common import InitBuilder
 from repro.data.pipeline import SyntheticLM, DataConfig
 from repro.runtime.pipeline import (PipelineConfig, repack_params,
                                     make_pipeline_prefill_step, make_pipeline_decode_step)
+from repro.launch.mesh import make_host_mesh
 from repro.sharding.api import use_mesh_context
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_host_mesh(2, 2, 2)
 cfg = configs.reduced("qwen3-1.7b").replace(n_layers=5)   # odd → uneven
 params = lm.build_params(cfg, InitBuilder(jax.random.PRNGKey(0), jnp.float32))
 data = SyntheticLM(cfg, DataConfig(batch=4, seq=16))

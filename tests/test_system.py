@@ -77,11 +77,6 @@ def test_serve_cli():
     assert "decode:" in cp.stdout
 
 
-@pytest.mark.xfail(
-    not hasattr(jax, "shard_map"),
-    reason="partial-manual shard_map (axis_names=) requires jax>=0.6; "
-           "this jax lowers axis_index to an unpartitionable PartitionId",
-    strict=False)
 def test_train_pipeline_cli_with_auto_partition():
     """Multi-pod GPipe on forced host devices + ParetoPipe-chosen cuts."""
     cp = _run(["repro.launch.train", "--arch", "qwen3-1.7b", "--reduced",
@@ -115,3 +110,39 @@ def test_loss_decreases_on_learnable_task():
             first = float(metrics["loss"])
     last = float(metrics["loss"])
     assert last < first * 0.7, (first, last)
+
+
+def test_compile_cache_dir(monkeypatch, tmp_path):
+    """The entry points' compile cache: JAX_COMPILATION_CACHE_DIR wins
+    and is left to JAX; otherwise a fixed, git-ignored <repo>/.jax_cache."""
+    from pathlib import Path
+
+    from repro.launch.compile_cache import REPO_CACHE_DIR, enable_compile_cache
+
+    repo = Path(__file__).resolve().parents[1]
+    assert REPO_CACHE_DIR == repo / ".jax_cache"
+    assert ".jax_cache/" in (repo / ".gitignore").read_text().splitlines()
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        assert enable_compile_cache() == str(REPO_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(REPO_CACHE_DIR)
+        jax.config.update("jax_compilation_cache_dir", was)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == was
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
+def test_chip_smoke_refuses_without_a_chip():
+    """chip_smoke.py exits non-zero before any work, and prints no
+    result, when JAX has no TPU."""
+    root = os.path.join(os.path.dirname(__file__), "..")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)
+    cp = subprocess.run([sys.executable, "chip_smoke.py"], cwd=root, env=env,
+                        capture_output=True, text=True, timeout=120)
+    assert cp.returncode != 0, cp.stdout + cp.stderr
+    assert cp.stdout == ""
+    assert "needs 1 TPU chip" in cp.stderr

@@ -22,7 +22,7 @@ from repro.runtime.adaptive import AdaptiveRuntime
 from repro.runtime.edge import EdgePipeline
 from repro.runtime.transport import (BATCH, PROBE, HopSpec, ShmemChannel,
                                      SocketChannel, TransferRecord,
-                                     TransportError, get_transport,
+                                     TransportError, as_jax, get_transport,
                                      record_trace)
 
 
@@ -92,6 +92,16 @@ def test_unknown_transport_rejected():
         get_transport("carrier-pigeon")
 
 
+def test_as_jax_aliases_what_dlpack_exports_and_nothing_is_swallowed():
+    x = np.arange(6, dtype=np.float32)
+    y = as_jax(x)
+    assert isinstance(y, jax.Array) and np.array_equal(np.asarray(y), x)
+    ro = np.frombuffer(x.tobytes(), np.float32)   # read-only: not exportable
+    assert as_jax(ro) is ro
+    with pytest.raises(jax.errors.JaxRuntimeError):   # non-compact view
+        as_jax(np.zeros((4, 4), np.float32)[:, ::2])
+
+
 # --------------------------------------------------------------------------- #
 # Scenario-level transport declarations
 # --------------------------------------------------------------------------- #
@@ -152,6 +162,18 @@ def test_transport_parity(mobilenet):
     pipe = EdgePipeline(m, params, (5, 12), scen)
     y2, _, _ = pipe.run_one(x)
     assert np.array_equal(outs["emulated"], np.asarray(y2))
+
+
+@pytest.mark.parametrize("name", ["emulated", "socket"])
+def test_worker_platforms_reported(mobilenet, name):
+    """Thread stages compute on this process's device; a spawned worker
+    pins its JAX to the host CPU before touching a backend and reports
+    the platform it came up on."""
+    m, params = mobilenet
+    with EdgePipeline(m, params, (5, 12), scenarios.get("pi_pi_gpu"),
+                      transport=name) as pipe:
+        want = jax.devices()[0].platform if name == "emulated" else "cpu"
+        assert pipe.worker_platforms == [want] * 3
 
 
 def test_socket_pipeline_migrates_and_records(mobilenet):
