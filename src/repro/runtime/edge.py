@@ -50,11 +50,13 @@ from typing import Callable, Literal, Sequence
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core.devices import AnyLink, Link, LinkTrace
 from ..core.scenarios import Scenario
 from . import transport as T
 from .sanitizer import maybe_sanitize, sanitize_enabled
+from .spans import current_seq, set_seq
 from .transport import (BATCH, CANCEL, CLOCK, ERROR, PROBE, RECONFIG, STATS,
                         STOP, WARMUP, Channel, HopMeter, HopSpec,
                         TransferRecord, TransportError, TransportTimeout,
@@ -75,6 +77,12 @@ class StageStats:
     cpu_s: float = 0.0              # worker CPU time (thread/process clock)
     cpu_pct: float = 0.0
     mem_pct: float = 0.0
+
+
+def _named(fn: Callable, name: str) -> Callable:
+    """``fn``, renamed: ``jax.jit`` names its program after it."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
 
 
 class Worker:
@@ -110,12 +118,15 @@ class Worker:
             for l, p in zip(layers, ps):
                 x = l.apply(p, x)
             return x
-        # ``fn(params, x)``: the whole stage as one program
-        self.fn = jax.jit(fused)
+        # ``fn(params, x)``: the whole stage as one program, named after
+        # the worker and its blocks (``jit_worker1_blocks_0_1`` in a trace)
+        self.fn = jax.jit(_named(fused, f"{name}_blocks_{lo}_{hi}"))
         if backend == "rpc":
             # module-granularity dispatch, one jitted call per block
-            self._calls = [(jax.jit(lambda p, x, l=l: l.apply(p, x)), p)
-                           for l, p in zip(layers, self.params)]
+            self._calls = [
+                (jax.jit(_named(lambda p, x, l=l: l.apply(p, x),
+                                f"{name}_blocks_{j}_{j + 1}")), p)
+                for j, l, p in zip(range(lo, hi), layers, self.params)]
         else:
             self._calls = [(self.fn, self.params)]
 
@@ -128,13 +139,16 @@ class Worker:
     def run(self, x):
         t0 = time.perf_counter()
         c0 = self._cpu_clock()
-        for fn, p in self._calls:
-            if self.backend == "rpc":
-                # serialize/deserialize at every module-call boundary
-                x = _Serializer.loads(_Serializer.dumps(x))
-                time.sleep(RPC_PER_CALL_OVERHEAD_S)
-            x = fn(p, x)
-        x = jax.block_until_ready(x)
+        ids = dict(seq=current_seq(), stage=self.name)
+        with TraceAnnotation("stage.dispatch", **ids):
+            for fn, p in self._calls:
+                if self.backend == "rpc":
+                    # serialize/deserialize at every module-call boundary
+                    x = _Serializer.loads(_Serializer.dumps(x))
+                    time.sleep(RPC_PER_CALL_OVERHEAD_S)
+                x = fn(p, x)
+        with TraceAnnotation("stage.sync_wait", **ids):
+            x = jax.block_until_ready(x)
         if self.pace_s > 0.0:
             rem = self.pace_s - (time.perf_counter() - t0)
             if rem > 0:
@@ -399,6 +413,8 @@ class _ThreadEngine:
         pipe = self.pipe
         last = i == pipe.n_stages - 1
         failed = False
+        name, r = f"worker{i + 1}", pipe.replicas[i]
+        n_batches = 0                         # BATCH tokens taken here
         # flush-cancel skip window: ``cancel_flush`` bumps the shared
         # epoch out-of-band (a plain int read — GIL-atomic), so batches
         # still queued ahead of the in-band CANCEL fence skip compute
@@ -407,12 +423,22 @@ class _ThreadEngine:
         # the process-engine twin.
         fence_seen = 0
         while True:
+            # the session's seq of this thread's next batch: replica m
+            # of r takes every r-th batch of the stripe (exact while no
+            # PROBE rides the stripe between them)
+            seq = m + r * n_batches
             try:
                 # bounded wait (pipecheck R6): a wedged upstream must not
                 # park this thread beyond the doorbell cadence
-                kind, obj = ingress.recv(timeout=1.0)
+                with TraceAnnotation("stage.recv_wait", seq=seq,
+                                     stage=name):
+                    kind, obj = ingress.recv(timeout=1.0)
             except TransportTimeout:
                 continue
+            if kind == BATCH:
+                n_batches += 1
+            # the spans of the stage's program and of its egress hop
+            set_seq(seq if kind == BATCH else -1)
             if kind == STOP:
                 egress.send(None, kind=STOP)
                 return

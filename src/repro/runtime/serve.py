@@ -23,8 +23,8 @@ underlying :class:`~repro.runtime.session.Session` pipeline through a
   in-flight window) against per-tenant latency SLOs, applied to the
   session via ``Session.set_inflight``.
 * **accounts per tenant** — every request finishes with a
-  :class:`QoSRecord` splitting queueing time vs processing latency vs
-  estimated wire time, drained like violations/recoveries
+  :class:`QoSRecord` splitting queueing time vs processing latency,
+  drained like violations/recoveries
   (module-level :func:`drain_qos` or per-gateway ``Gateway.drain_qos``).
 * **cancels expired work** — ``Gateway.cancel_inflight`` flushes the
   in-flight window over the ``CANCEL`` token (workers skip compute on
@@ -47,6 +47,7 @@ from dataclasses import dataclass
 from typing import TYPE_CHECKING, Iterable, Sequence
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core.scenarios import TenantMix, TenantSpec
 from .session import AdaptiveController, PinnedController, Session, \
@@ -67,8 +68,8 @@ __all__ = [
 @dataclass(frozen=True)
 class QoSRecord:
     """One served request, decomposed the way an SLO postmortem needs:
-    how long it *queued* at the gateway, how long the pipeline *served*
-    it, and how much of that service was estimated *wire* time."""
+    how long it *queued* at the gateway and how long the pipeline
+    *served* it."""
 
     tenant: str
     req_id: int                 # per-tenant request index
@@ -76,7 +77,6 @@ class QoSRecord:
     t_s: float                  # completion time (pipeline clock)
     queue_s: float              # enqueue -> pipeline submit
     service_s: float            # pipeline submit -> arrival
-    wire_s: float               # estimated per-batch hop time share
     latency_s: float            # queue_s + service_s (the SLO quantity)
     rows: int                   # rows this request contributed
     coalesced: int              # requests sharing the micro-batch
@@ -102,39 +102,6 @@ def drain_qos() -> list[QoSRecord]:
 def _log_qos(gid: int, rec: QoSRecord) -> None:
     with _QLOCK:
         _QOS.append((gid, rec))
-
-
-# --------------------------------------------------------------------------- #
-# wire-time share of a served batch
-# --------------------------------------------------------------------------- #
-class _WireMeter:
-    """Per-batch wire-time estimate from the pipeline's lifetime hop
-    counters (same delta discipline as the energy meter: exact when
-    batch-synchronous, a window mean when pipelined, checkpoint-lagged
-    under process transports)."""
-
-    def __init__(self, pipe: "EdgePipeline"):
-        self.pipe = pipe
-        self.wire_per_batch = 0.0
-        self._snap()
-
-    def _snap(self) -> None:
-        nets = self.pipe.nets
-        self._elapsed = sum(n.total_elapsed_s for n in nets)
-        self._batches = min((n.total_transfers for n in nets), default=0)
-
-    def update(self) -> float:
-        nets = self.pipe.nets
-        elapsed = sum(n.total_elapsed_s for n in nets)
-        batches = min((n.total_transfers for n in nets), default=0)
-        if batches < self._batches:           # migration reset the meters
-            self._snap()
-            return self.wire_per_batch
-        d = batches - self._batches
-        if d >= 1:
-            self.wire_per_batch = max(elapsed - self._elapsed, 0.0) / d
-            self._elapsed, self._batches = elapsed, batches
-        return self.wire_per_batch
 
 
 # --------------------------------------------------------------------------- #
@@ -229,7 +196,6 @@ class Gateway:
                                                          self._win)]
         # meters
         self._emeter = _EnergyMeter(pipe)
-        self._wmeter = _WireMeter(pipe)
         self.qos_recent: deque[QoSRecord] = deque(maxlen=256)
         self.closed = False
 
@@ -422,26 +388,29 @@ class Gateway:
                     or now - oldest >= self.batch_window_s)
             if not ripe:
                 return
-            picked = self._gather()
-            if not picked:
-                return
-            parts = [r.payload for _, r in picked]
-            big = parts[0] if len(parts) == 1 else np.concatenate(parts, 0)
-            rows = big.shape[0]
-            if self.deterministic and rows < self.max_batch:
-                pad = np.zeros((self.max_batch - rows,) + big.shape[1:],
-                               big.dtype)
-                big = np.concatenate([big, pad], 0)
-            t_sub = time.perf_counter()
-            seq = self._session.submit(big)
-            members, row0 = [], 0
-            for name, req in picked:
-                m = _Member(name, req, row0)
-                row0 = m.row1
-                members.append(m)
-            self._members[seq] = members
-            self._submit_times[seq] = t_sub
-            self._inflight_order.append(seq)
+            with TraceAnnotation("gateway.admit",
+                                 seq=self._session._next_seq):
+                picked = self._gather()
+                if not picked:
+                    return
+                parts = [r.payload for _, r in picked]
+                big = parts[0] if len(parts) == 1 \
+                    else np.concatenate(parts, 0)
+                rows = big.shape[0]
+                if self.deterministic and rows < self.max_batch:
+                    pad = np.zeros((self.max_batch - rows,) + big.shape[1:],
+                                   big.dtype)
+                    big = np.concatenate([big, pad], 0)
+                t_sub = time.perf_counter()
+                seq = self._session.submit(big)
+                members, row0 = [], 0
+                for name, req in picked:
+                    m = _Member(name, req, row0)
+                    row0 = m.row1
+                    members.append(m)
+                self._members[seq] = members
+                self._submit_times[seq] = t_sub
+                self._inflight_order.append(seq)
 
     def _advance(self) -> bool:
         """Deliver the next completed micro-batch (blocking); → False
@@ -468,10 +437,17 @@ class Gateway:
                 return True
             return False
         seq = self._inflight_order.popleft()
+        with TraceAnnotation("gateway.deliver", seq=seq):
+            self._deliver(seq, value, now)
+        self._admit()
+        return True
+
+    def _deliver(self, seq: int, value, now: float) -> None:
+        """Hand micro-batch ``seq``'s output, arrived at ``now``, to its
+        requests: each one's rows, its QoS record, and the AIMD step."""
         members = self._members.pop(seq, [])
         t_sub = self._submit_times.pop(seq, now)
         energy = self._emeter.update()
-        wire = self._wmeter.update()
         n = max(len(members), 1)
         pad_rows = self.max_batch if self.deterministic \
             else (members[-1].row1 if members else 1)
@@ -486,7 +462,7 @@ class Gateway:
                 tenant=m.tenant, req_id=m.req_id, seq=seq,
                 t_s=self.pipe.clock(),
                 queue_s=t_sub - m.t_enq, service_s=now - t_sub,
-                wire_s=wire, latency_s=latency,
+                latency_s=latency,
                 rows=m.row1 - m.row0, coalesced=len(members),
                 occupancy=(members[-1].row1 / pad_rows) if members else 0.0,
                 energy_j=energy / n, slo_s=spec.slo_s, violated=violated)
@@ -495,8 +471,6 @@ class Gateway:
             self._results[m.tenant].append((m.req_id, y))
             self._events.append((m.tenant, m.req_id))
         self._aimd(seq, violated_any)
-        self._admit()
-        return True
 
     def _aimd(self, seq: int, violated: bool) -> None:
         win0 = self._win

@@ -49,6 +49,7 @@ from dataclasses import dataclass
 from typing import TYPE_CHECKING, Literal, Protocol, Sequence, runtime_checkable
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core.autosplit import AdaptiveSplitter, LinkEstimator
 from .transport import (BATCH, CANCEL, CLOCK, ERROR, PROBE, RECONFIG, STATS,
@@ -626,7 +627,9 @@ class Session:
     def _pump(self, timeout: float | None = None) -> None:
         """Handle exactly one arrival at the result end."""
         try:
-            kind, obj = self._engine.poll(timeout or self.pipe.timeout_s)
+            with TraceAnnotation("session.result_wait",
+                                 seq=self._next_arrival):
+                kind, obj = self._engine.poll(timeout or self.pipe.timeout_s)
         except TransportTimeout:
             self._failed = True
             raise

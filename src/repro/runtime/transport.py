@@ -56,9 +56,11 @@ from dataclasses import dataclass
 from typing import Callable, NamedTuple, Sequence
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core.devices import (AnyLink, Link, LinkTrace, attribute_bandwidth,
                             fit_link_params)
+from .spans import current_seq
 
 # message kinds (in-band, ordered with the batches around them).
 # CANCEL is the flush fence: submitted behind canceled in-flight
@@ -490,15 +492,20 @@ class EmulatedChannel(Channel):
         packing: the next stage computes on the degraded tensor, so
         emulated runs carry the codec's accuracy cost end to end.
         → (wire bytes, raw bytes, decoded payload)."""
-        host = np.asarray(payload)
+        ids = dict(seq=current_seq(), hop=self.hop.index)
+        with TraceAnnotation("hop.d2h", **ids):
+            host = np.asarray(payload)
         raw = host.size * host.dtype.itemsize
         codec = self.codec
         if not (codec.code and host.size and codec.supports(host.dtype)):
             return raw, raw, payload
         if not host.flags.c_contiguous:
             host = np.ascontiguousarray(host)
-        buf = codec.encode(host)
-        return len(buf), raw, codec.decode(buf, host.shape, host.dtype)
+        with TraceAnnotation("hop.encode", **ids):
+            buf = codec.encode(host)
+        with TraceAnnotation("hop.decode", **ids):
+            out = codec.decode(buf, host.shape, host.dtype)
+        return len(buf), raw, out
 
     def send(self, payload=None, kind: int = BATCH):
         if kind == BATCH:
@@ -508,7 +515,9 @@ class EmulatedChannel(Channel):
             else:
                 nbytes, raw, out = self._roundtrip(payload)
             dt = self.emulate(nbytes, raw_bytes=raw)
-            self._q.put((kind, out))
+            with TraceAnnotation("hop.put_wait", seq=current_seq(),
+                                 hop=self.hop.index):
+                self._q.put((kind, out))
             return TransferRecord(nbytes, dt, self._clock(), raw)
         if (kind == WARMUP and self.hop.framing != "pickle"
                 and (isinstance(payload, np.ndarray)
