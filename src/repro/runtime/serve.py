@@ -444,8 +444,13 @@ class Gateway:
 
     def _deliver(self, seq: int, value, now: float) -> None:
         """Hand micro-batch ``seq``'s output, arrived at ``now``, to its
-        requests: each one's rows, its QoS record, and the AIMD step."""
+        requests: each one's rows, its QoS record, and the AIMD step.
+        The output comes to the host in one copy, which the requests'
+        rows are then cut from."""
         members = self._members.pop(seq, [])
+        with TraceAnnotation("gateway.fetch", seq=seq,
+                             requests=len(members)):
+            host = np.asarray(value)
         t_sub = self._submit_times.pop(seq, now)
         energy = self._emeter.update()
         n = max(len(members), 1)
@@ -453,7 +458,7 @@ class Gateway:
             else (members[-1].row1 if members else 1)
         violated_any = False
         for m in members:
-            y = np.array(value[m.row0:m.row1])   # detach from the pad
+            y = np.array(host[m.row0:m.row1])    # detach from the pad
             spec = self.tenants[m.tenant]
             latency = now - m.t_enq
             violated = latency > spec.slo_s

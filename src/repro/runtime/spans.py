@@ -13,8 +13,10 @@ Stage spans also carry ``stage`` (the worker's name) and hop spans
 not work.
 
   gateway.admit        Gateway._admit: gather, concat, pad, submit
-  gateway.deliver      Gateway._advance: slice and copy each request's
-                       rows off the device, meters, QoS records
+  gateway.deliver      Gateway._advance: split one host copy into the
+                       requests' rows, meters, QoS records
+  gateway.fetch        Gateway._deliver: the micro-batch's one copy to
+                       the host; ``requests`` is how many it serves
   session.result_wait  Session._pump: blocked on the pipeline
   stage.recv_wait      a stage thread waiting for its input
   stage.dispatch       Worker.run: the program launch, with the upload

@@ -9,8 +9,11 @@ invariant), with zero cross-tenant leakage and zero sanitizer
 violations.  On top of the matrix: a chaos worker-kill proving
 per-tenant replay isolation, the AIMD admission window under SLO
 pressure, fleet-objective aggregation, QoS decomposition, cancellation
-through the CANCEL fence, and the deep-sanitize tier end to end.
+through the CANCEL fence, the demux's one host copy per micro-batch,
+and the deep-sanitize tier end to end.
 """
+import time
+
 import jax
 import numpy as np
 import pytest
@@ -21,6 +24,7 @@ from repro.core.devices import LAN_PI_GPU
 from repro.runtime import (EdgePipeline, FaultPlan, FleetController,
                            Gateway, QoSRecord, drain_qos, drain_recoveries,
                            drain_violations)
+from repro.runtime.serve import _Member, _Req
 
 MAX_BATCH = 8
 N_REQS = 3                                    # requests per tenant
@@ -337,6 +341,62 @@ def test_gateway_clean_under_deep_sanitize(tiny, solo_refs, monkeypatch):
             assert np.array_equal(np.asarray(y), np.asarray(ref))
     assert drain_violations() == []
     pipe.close()
+
+
+# --------------------------------------------------------------------------- #
+# the demux: one host copy per micro-batch
+# --------------------------------------------------------------------------- #
+class _FetchOnly:
+    """A micro-batch output that counts its copies to the host and
+    refuses to be sliced where it lies."""
+
+    def __init__(self, buf: np.ndarray):
+        self.buf = buf
+        self.copies = 0
+
+    def __array__(self, dtype=None, copy=None):
+        self.copies += 1
+        return self.buf
+
+    def __getitem__(self, key):
+        raise AssertionError("the output was sliced before its copy")
+
+
+@pytest.mark.parametrize("deterministic", [True, False])
+@pytest.mark.parametrize("rows", [[3], [2, 1], [3, 1, 4]])
+def test_deliver_copies_each_micro_batch_once(tiny, rows, deterministic):
+    m, params = tiny
+    pipe = EdgePipeline(m, params, 2, [LAN_PI_GPU])
+    tenants = [scenarios.TenantSpec("a", slo_s=30.0),
+               scenarios.TenantSpec("b", slo_s=30.0)]
+    with Gateway(pipe, tenants, max_batch=MAX_BATCH,
+                 deterministic=deterministic) as gw:
+        members, row0 = [], 0
+        for j, r in enumerate(rows):
+            req = _Req(j, np.zeros((r, 32, 32, 3), np.float32),
+                       time.perf_counter())
+            members.append(_Member(tenants[j % 2].name, req, row0))
+            row0 += r
+        n_out = MAX_BATCH if deterministic else row0
+        buf = np.asarray(jax.random.normal(jax.random.PRNGKey(len(rows)),
+                                           (n_out, 10)))
+        buf.flags.writeable = False           # as a device array reads
+        out = _FetchOnly(buf)
+        gw._members[0] = members
+        gw._deliver(0, out, time.perf_counter())
+        assert out.copies == 1
+        got = [gw._results[mb.tenant].popleft()[1] for mb in members]
+        qos = gw.drain_qos()
+    pipe.close()
+    assert [q.rows for q in qos] == rows
+    for mb, y in zip(members, got):
+        ref = buf[mb.row0:mb.row1]
+        assert y.dtype == ref.dtype
+        assert np.array_equal(y.view(np.uint32), ref.view(np.uint32))
+        assert y.flags.owndata and not np.shares_memory(y, buf)
+    for i, y in enumerate(got):
+        for z in got[i + 1:]:
+            assert not np.shares_memory(y, z)
 
 
 # --------------------------------------------------------------------------- #

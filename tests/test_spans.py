@@ -51,9 +51,10 @@ def _host_spans(trace_dir) -> list[tuple[str, dict]]:
     """(name, metadata) of every span of ``spans``'s table in the trace."""
     (path,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
                                      "*.xplane.pb"))
-    names = {"gateway.admit", "gateway.deliver", "session.result_wait",
-             "stage.recv_wait", "stage.dispatch", "stage.sync_wait",
-             "hop.d2h", "hop.encode", "hop.decode", "hop.put_wait"}
+    names = {"gateway.admit", "gateway.deliver", "gateway.fetch",
+             "session.result_wait", "stage.recv_wait", "stage.dispatch",
+             "stage.sync_wait", "hop.d2h", "hop.encode", "hop.decode",
+             "hop.put_wait"}
     out = []
     for plane in ProfileData.from_file(path).planes:
         if plane.name != "/host:CPU":
@@ -78,13 +79,17 @@ def test_every_micro_batch_leaves_its_spans_under_its_seq(tiny, tmp_path):
             for j in range(N_REQS):
                 gw.submit(tenants[j % 2].name, rows[2 * j:2 * j + 2])
             out = gw.drain()
+        coalesced = {r.seq: r.coalesced for r in gw.drain_qos()}
     pipe.close()
     assert sum(len(v) for v in out.values()) == N_REQS
 
     by_seq: dict[int, Counter] = defaultdict(Counter)
     where: dict[tuple[str, int], set] = defaultdict(set)
+    served: dict[int, list[int]] = defaultdict(list)
     for name, ids in _host_spans(str(tmp_path)):
         by_seq[ids["seq"]][name] += 1
+        if name == "gateway.fetch":
+            served[ids["seq"]].append(ids["requests"])
         if "stage" in ids:
             where[(name, ids["seq"])].add(ids["stage"])
         if "hop" in ids:
@@ -95,7 +100,9 @@ def test_every_micro_batch_leaves_its_spans_under_its_seq(tiny, tmp_path):
     stages = {"worker1", "worker2", "worker3"}
     for s in seqs:
         n = by_seq[s]
-        assert (n["gateway.admit"], n["gateway.deliver"]) == (1, 1), (s, n)
+        assert (n["gateway.admit"], n["gateway.deliver"],
+                n["gateway.fetch"]) == (1, 1, 1), (s, n)
+        assert served[s] == [coalesced[s]], (s, served[s])
         assert n["stage.dispatch"] == n["stage.sync_wait"] == 3, (s, n)
         assert n["hop.d2h"] == n["hop.put_wait"] == 2, (s, n)
         assert n["hop.encode"] == n["hop.decode"] == 0, (s, n)
