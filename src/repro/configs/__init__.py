@@ -41,8 +41,9 @@ def reduced(name: str) -> ArchConfig:
     if c.family == "ssm":
         kw.update(n_heads=0, n_kv_heads=0, ssm_state=8)
     elif c.family == "hybrid":
-        kw.update(n_heads=4, n_kv_heads=4, ssm_state=8, ssm_head_dim=16,
-                  shared_attn_every=2, n_layers=4)
+        kw.update(n_heads=4, n_kv_heads=4, head_dim=32, ssm_state=8,
+                  ssm_head_dim=16, hybrid_layer_ids=(1, 3), n_layers=4,
+                  adapter_rank=8)
     elif c.family == "moe":
         kw.update(n_heads=4, n_kv_heads=2, n_experts=4, top_k=2)
     elif c.family == "encdec":

@@ -33,7 +33,8 @@ class Block:
     out_bytes: int               # activation bytes emitted per *sample*
     act_bytes: int = 0           # peak intermediate activation bytes (memory model)
     eff: float = 1.0             # achievable fraction of device peak (per-op-type)
-    shared_group: str | None = None   # weight-sharing group id (zamba2 shared block)
+    shared_group: str | None = None   # weight-sharing group id (zamba2 memory block)
+    shared_bytes: int = 0        # the group's weights, held once per stage that uses it
     broadcast_bytes: int = 0     # bytes every *later* stage needs (enc-dec cross-attn)
 
     def scaled(self, batch: int) -> "Block":
@@ -71,15 +72,7 @@ class BlockGraph:
     @property
     def total_weight_bytes(self) -> int:
         """Total parameter bytes, counting each shared group once."""
-        seen: set[str] = set()
-        total = 0
-        for b in self.blocks:
-            if b.shared_group is not None:
-                if b.shared_group in seen:
-                    continue
-                seen.add(b.shared_group)
-            total += b.weight_bytes
-        return total
+        return self.segment_weight_bytes(0, self.n_blocks)
 
     def segment_flops(self, lo: int, hi: int) -> float:
         """FLOPs of blocks[lo:hi]."""
@@ -91,11 +84,10 @@ class BlockGraph:
         seen: set[str] = set()
         total = 0
         for b in self.blocks[lo:hi]:
-            if b.shared_group is not None:
-                if b.shared_group in seen:
-                    continue
-                seen.add(b.shared_group)
             total += b.weight_bytes
+            if b.shared_group is not None and b.shared_group not in seen:
+                seen.add(b.shared_group)
+                total += b.shared_bytes
         return total
 
     def cut_bytes(self, p: int) -> int:

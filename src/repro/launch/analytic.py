@@ -78,13 +78,15 @@ def _mamba1_flops(cfg) -> float:
 
 
 def _mamba2_flops(cfg, chunk: int) -> float:
-    D, di, N = cfg.d_model, cfg.d_inner, cfg.ssm_state
+    D, di, N, G = cfg.d_model, cfg.d_inner, cfg.ssm_state, cfg.ssm_ngroups
     H, P = cfg.ssm_heads, cfg.ssm_head_dim
-    d_in = 2 * di + 2 * N + H
-    proj = 2.0 * D * d_in + 2.0 * (di + 2 * N) * cfg.ssm_conv + 2.0 * di * D
+    d_xbc = di + 2 * G * N
+    proj = 2.0 * D * (di + d_xbc + H) + 2.0 * d_xbc * cfg.ssm_conv \
+        + 2.0 * di * D
     L = chunk
-    # per token: CB^T row (2·L·N) + att·dtx (2·L·H·P) + carry in/out
-    intra = 2.0 * L * N + 2.0 * L * H * P
+    # per token: a CB^T row per group (2·L·G·N) + att·dtx (2·L·H·P) +
+    # carry in/out; the chunk's L×L is computed whole, then masked
+    intra = 2.0 * L * G * N + 2.0 * L * H * P
     inter = 4.0 * H * P * N
     return proj + intra + inter
 
@@ -105,8 +107,14 @@ def _layer_fwd_flops(cfg, ctx_len: float) -> float:
 
 
 def _shared_block_flops(cfg, ctx_len: float) -> float:
-    return (_attn_proj_flops(cfg) + _attn_score_flops(cfg, ctx_len)
-            + _mlp_flops(cfg))
+    """One application of a Zamba2 memory block, per token: q/k/v from
+    the concatenated 2·d_model width, o back to d_model, scores, the
+    gated MLP with the application's rank-r adapter, and its linear."""
+    D, H, KV, hd, F = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.hd, cfg.d_ff
+    proj = 2.0 * 2 * D * (H + 2 * KV) * hd + 2.0 * H * hd * D
+    adapter = 2.0 * D * cfg.adapter_rank + 2.0 * cfg.adapter_rank * 2 * F
+    return (proj + _attn_score_flops(cfg, ctx_len) + _mlp_flops(cfg)
+            + adapter + 2.0 * D * D)
 
 
 def trunk_fwd_flops(cfg, tokens: float, ctx_len: float) -> float:
@@ -242,7 +250,8 @@ def _cache_bytes(cfg, B, S) -> float:
         ssm = cfg.n_layers * B * (cfg.ssm_heads * cfg.ssm_head_dim
                                   * cfg.ssm_state * 4
                                   + (cfg.ssm_conv - 1)
-                                  * (cfg.d_inner + 2 * cfg.ssm_state) * 2)
+                                  * (cfg.d_inner + 2 * cfg.ssm_ngroups
+                                     * cfg.ssm_state) * 2)
         attn = 2.0 * cfg.n_attn_apps * B * S * cfg.n_kv_heads * cfg.hd * 2
         return ssm + attn
     raise ValueError(cfg.family)

@@ -125,11 +125,11 @@ def cache_specs(cfg: ArchConfig, B: int, S: int, ctx: MeshContext | None,
                 "pos": pos}
     if cfg.family == "hybrid":
         from ..runtime.pipeline import n_attn_slots
-        d_xbc = cfg.d_inner + 2 * cfg.ssm_state
+        d_xbc = cfg.d_inner + 2 * cfg.ssm_ngroups * cfg.ssm_state
         if pcfg is None:
             a_lead, a_lead_ax = (cfg.n_attn_apps,), ("layers",)
         else:
-            a_lead = (pcfg.n_stages, n_attn_slots(cfg, lead[-1]))
+            a_lead = (pcfg.n_stages, n_attn_slots(cfg, pcfg))
             a_lead_ax = ("stage", "layers")
         return {"conv": _sds(ctx, (*lead, B, cfg.ssm_conv - 1, d_xbc), dt,
                              (*lead_ax, "batch", "kernel", "conv_dim")),

@@ -87,12 +87,14 @@ def _block_attn(q, k, v, bias, scale):
     return m, ev, jnp.sum(e, axis=-1)
 
 
-def attend_prefill(cfg, q, k, v, *, causal: bool = True):
-    """q: (B,S,H,hd); k,v: (B,T,KV,hd) → (B,S,H,hd)."""
+def attend_prefill(cfg, q, k, v, *, causal: bool = True,
+                   scale: float | None = None):
+    """q: (B,S,H,hd); k,v: (B,T,KV,hd) → (B,S,H,hd).  ``scale``
+    multiplies the scores (default ``hd ** -0.5``)."""
     B, S, H, hd = q.shape
     T, KV = k.shape[1], k.shape[2]
     G = H // KV
-    scale = 1.0 / math.sqrt(hd)
+    scale = scale or 1.0 / math.sqrt(hd)
     qg = q.reshape(B, S, KV, G, hd)
 
     chunk = cfg.attn_chunk
@@ -153,13 +155,14 @@ def attend_prefill(cfg, q, k, v, *, causal: bool = True):
 # --------------------------------------------------------------------------- #
 # Decode attention against a KV cache
 # --------------------------------------------------------------------------- #
-def attend_decode(cfg, q, k_cache, v_cache, pos):
+def attend_decode(cfg, q, k_cache, v_cache, pos, *,
+                  scale: float | None = None):
     """q: (B,1,H,hd); caches: (B,Smax,KV,hd); pos: scalar index of the
     current token (cache already contains it)."""
     B, _, H, hd = q.shape
     KV = k_cache.shape[2]
     G = H // KV
-    scale = 1.0 / math.sqrt(hd)
+    scale = scale or 1.0 / math.sqrt(hd)
     qg = q.reshape(B, KV, G, hd)
     s = jnp.einsum("bkgd,bskd->bkgs", qg, k_cache,
                    preferred_element_type=jnp.float32) * scale

@@ -21,51 +21,59 @@ def _layer_weight_bytes(cfg: ArchConfig) -> int:
     n = cfg.param_count()
     head = 0 if cfg.tie_embeddings else cfg.d_model * cfg.vocab
     trunk = n - cfg.vocab * cfg.d_model - head
-    if cfg.family == "hybrid":
-        trunk -= (2 * cfg.d_model * (cfg.n_heads + 2 * cfg.n_kv_heads)
-                  * cfg.hd // 2)  # shared block roughly excluded below
     return int(trunk / cfg.n_layers * 2)
 
 
 def arch_block_graph(cfg: ArchConfig, seq: int, *, train: bool = False,
-                     per_sample: bool = True) -> BlockGraph:
+                     per_sample: bool = True,
+                     prefill: bool = False) -> BlockGraph:
     """Per-sample block graph at sequence length ``seq``.
 
-    Blocks: [embed] + n_layers × [layer] + [head].  For hybrid archs the
-    shared attention block is folded into the layers it precedes (with
-    ``shared_group`` so its weights are counted once per stage).
+    Blocks: [embed] + n_layers × [layer] + [head], as ``LMChain`` runs
+    them.  ``prefill``: the head computes the last position's logits
+    only, and emits them in float32.  For the hybrid family a cut carries
+    the residual stream and the original embeddings, and each layer that
+    applies a memory block charges that application's work, holds its
+    adapter and linear, and names the block's ``shared_group`` (its
+    weights held once per stage).
     """
     ctx = (seq + cfg.attn_chunk) / 2 if seq > cfg.attn_chunk else (seq + 1) / 2
     act = seq * cfg.d_model * 2              # bf16 inter-layer activation
+    if cfg.family == "hybrid":
+        act *= 2                             # + the original embeddings
     mult = 3.0 + (1.0 if (train and cfg.remat) else 0.0) if train else 1.0
 
     blocks = [Block("embed", flops=seq * cfg.d_model * mult,
                     weight_bytes=cfg.vocab * cfg.d_model * 2,
                     out_bytes=act, act_bytes=act * 2)]
-    lw = _layer_weight_bytes(cfg)
     per_layer = _layer_fwd_flops(cfg, ctx) * seq * mult
-    shared_extra = 0.0
+    lw, app_w, shared_w, app_flops = _layer_weight_bytes(cfg), 0, 0, 0.0
+    apps = (-1,) * cfg.n_layers
     if cfg.family == "hybrid":
-        shared_extra = _shared_block_flops(cfg, ctx) * seq * mult
-    for i in range(cfg.n_layers):
-        flops = per_layer
-        shared_group = None
-        wb = lw
-        if cfg.family == "hybrid" and i % cfg.shared_attn_every == 0:
-            flops += shared_extra
-            shared_group = "shared_attn"
-            wb += int((2 * cfg.d_model * (cfg.n_heads + 2 * cfg.n_kv_heads)
-                       * cfg.hd + 3 * cfg.d_model * cfg.d_ff) * 2)
-        blocks.append(Block(f"layer{i:03d}", flops=flops, weight_bytes=wb,
-                            out_bytes=act, act_bytes=act * 4,
-                            shared_group=shared_group))
-    head_w = 0 if cfg.tie_embeddings else cfg.d_model * cfg.vocab * 2
-    blocks.append(Block("head", flops=_logit_flops(cfg, seq) * (3 if train else 1),
+        lw = cfg.mamba2_layer_params() * 2
+        app_w = cfg.app_params() * 2
+        shared_w = cfg.mem_block_params() * 2
+        app_flops = _shared_block_flops(cfg, ctx) * seq * mult
+        apps = cfg.attn_apps
+    for i, a in enumerate(apps):
+        hyb = a >= 0
+        blocks.append(Block(
+            f"layer{i:03d}", flops=per_layer + (app_flops if hyb else 0.0),
+            weight_bytes=lw + (app_w if hyb else 0), out_bytes=act,
+            act_bytes=act * 4,
+            shared_group=f"mem_block{a % cfg.n_mem_blocks}" if hyb else None,
+            shared_bytes=shared_w if hyb else 0))
+    head_w = (0 if cfg.tie_embeddings else cfg.d_model * cfg.vocab * 2) \
+        + (cfg.d_model * 2 if cfg.family == "hybrid" else 0)
+    positions = 1 if prefill else seq
+    blocks.append(Block("head",
+                        flops=_logit_flops(cfg, positions) * (3 if train else 1),
                         weight_bytes=head_w,
-                        out_bytes=seq * 4,        # predictions
-                        act_bytes=seq * cfg.vocab * 4))
+                        out_bytes=cfg.vocab * 4 if prefill else seq * 4,
+                        act_bytes=positions * cfg.vocab * 4))
     return BlockGraph(name=cfg.name, blocks=tuple(blocks),
-                      input_bytes=seq * 4, output_bytes=seq * 4)
+                      input_bytes=seq * 4,
+                      output_bytes=cfg.vocab * 4 if prefill else seq * 4)
 
 
 def choose_pipeline_cuts(cfg: ArchConfig, seq: int, n_pods: int,

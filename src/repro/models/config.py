@@ -49,9 +49,18 @@ class ArchConfig:
     ssm_dt_rank: int = 0                # mamba1; default d_model/16
     ssm_head_dim: int = 64              # mamba2
     ssm_chunk: int = 256                # chunked-scan chunk length
+    ssm_ngroups: int = 1                # mamba2: B/C groups (heads per group share them)
+    ssm_dt_min: float = 0.0             # mamba2: floor on the softplus time step
 
-    # hybrid (zamba2): one shared attention block applied every k layers
-    shared_attn_every: int = 0
+    # hybrid (zamba2): Mamba-2 layers; at each of ``hybrid_layer_ids`` one
+    # of ``n_mem_blocks`` shared attention+MLP blocks (alternating) reads
+    # concat(hidden, original embeddings); its output, through the
+    # application's own rank-``adapter_rank`` MLP adapter and ``linear``,
+    # is added to that layer's Mamba input
+    hybrid_layer_ids: tuple[int, ...] = ()
+    n_mem_blocks: int = 1
+    adapter_rank: int = 0
+    mlp_act: str = "silu"               # gated MLP activation: silu | gelu (exact)
 
     # enc-dec (whisper)
     n_enc_layers: int = 0
@@ -92,11 +101,19 @@ class ArchConfig:
         return self.d_inner // self.ssm_head_dim
 
     @property
+    def attn_apps(self) -> tuple[int, ...]:
+        """Hybrid: per layer, the index of its shared-block application
+        (counting ``hybrid_layer_ids`` below ``n_layers``), -1 for a plain
+        Mamba layer.  Application ``a`` uses memory block
+        ``a % n_mem_blocks``."""
+        ids = [i for i in self.hybrid_layer_ids if i < self.n_layers]
+        return tuple(ids.index(i) if i in ids else -1
+                     for i in range(self.n_layers))
+
+    @property
     def n_attn_apps(self) -> int:
-        """Hybrid: number of shared-attention applications."""
-        if self.shared_attn_every <= 0:
-            return 0
-        return -(-self.n_layers // self.shared_attn_every)
+        """Hybrid: number of shared-block applications."""
+        return sum(a >= 0 for a in self.attn_apps)
 
     @property
     def is_attention_free(self) -> bool:
@@ -130,15 +147,6 @@ class ArchConfig:
                     + di * (R + 2 * N) + R * di + di  # x_proj, dt_proj(+bias)
                     + di * N + di                     # A_log, D
                     + di * D)                         # out_proj
-        def mamba2() -> int:
-            di, N, Hs = self.d_inner, self.ssm_state, self.ssm_heads
-            ng = 1  # single B/C group
-            d_xbc = di + 2 * ng * N
-            return (D * (2 * di + 2 * ng * N + Hs)    # in_proj → z,x,B,C,dt
-                    + d_xbc * self.ssm_conv + d_xbc   # conv
-                    + Hs + Hs + Hs                    # A_log, D, dt_bias
-                    + di + di * D)                    # gated rmsnorm, out_proj
-
         emb = V * D
         head = 0 if self.tie_embeddings else D * V
         norms2 = 2 * D   # per layer: 2 pre-norms (attn+mlp families)
@@ -153,15 +161,36 @@ class ArchConfig:
             per = mamba1() + D  # single pre-norm
             return emb + head + self.n_layers * per + D
         if self.family == "hybrid":
-            per = mamba2() + D
-            shared = attn() + mlp_dense(F) + norms2
-            return emb + head + self.n_layers * per + shared + D
+            return (emb + head + self.n_layers * self.mamba2_layer_params()
+                    + D + self.n_mem_blocks * self.mem_block_params()
+                    + self.n_attn_apps * self.app_params())
         if self.family == "encdec":
             enc_per = attn() + mlp_dense(F) + norms2
             dec_per = 2 * attn() + mlp_dense(F) + 3 * D
             return (emb + head + self.n_enc_layers * enc_per
                     + self.n_layers * dec_per + 2 * D)
         raise ValueError(self.family)
+
+    def mamba2_layer_params(self) -> int:
+        """A Mamba-2 layer with its pre-norm."""
+        D, di, N, Hs = self.d_model, self.d_inner, self.ssm_state, self.ssm_heads
+        d_xbc = di + 2 * self.ssm_ngroups * N
+        return (D * (di + d_xbc + Hs)             # in_proj → z, x, B, C, dt
+                + d_xbc * self.ssm_conv + d_xbc   # conv
+                + 3 * Hs                          # A_log, D, dt_bias
+                + di + di * D + D)                # gated norm, out_proj, pre-norm
+
+    def mem_block_params(self) -> int:
+        """Hybrid: one shared attention+MLP block over the 2·d_model concat."""
+        D, H, KV, hd = self.d_model, self.n_heads, self.n_kv_heads, self.hd
+        D2 = 2 * D
+        return (D2 + D2 * (H + 2 * KV) * hd + H * hd * D + D
+                + (3 if self.gated_mlp else 2) * D * self.d_ff)
+
+    def app_params(self) -> int:
+        """Hybrid: what one application owns: its adapter and its linear."""
+        D = self.d_model
+        return D * D + self.adapter_rank * (D + 2 * self.d_ff)
 
     def active_param_count(self) -> int:
         """MoE: params touched per token (for MODEL_FLOPS = 6·N_active·D)."""

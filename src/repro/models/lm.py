@@ -9,13 +9,18 @@ HLO size and compile time are O(1) in depth, which is what makes 80-layer
 Caches (serving):
   dense/moe/vlm : {"k","v": (L,B,Smax,KV,hd), "pos"}
   ssm           : {"conv": (L,B,K-1,di), "h": (L,B,di,N), "pos"}
-  hybrid        : ssm fields (mamba2 shapes) + {"ak","av": (A,B,Smax,KV,hd)}
+  hybrid        : ssm fields (mamba2 shapes) + {"ak","av": (A,B,Smax,KV,hd)},
+                  one K/V slot per shared-block application
   encdec        : dense fields + {"ck","cv": (L,B,F,KV,hd)} cross-attn
+
+``LMChain`` exposes the same layers as the chain of blocks the split
+runtime cuts (``runtime.edge``): embed, one block per layer, head.
 """
 from __future__ import annotations
 
 import math
-from functools import partial
+from dataclasses import dataclass
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
@@ -107,8 +112,39 @@ def build_params(cfg, b: Builder) -> dict:
     sb = StackedBuilder(b, cfg.n_layers)
     params["layers"] = layer_params(cfg, sb)
     if cfg.family == "hybrid":
-        params["shared"] = _attn_block_params(b, cfg, "shared")
+        params["shared"] = mem_block_params(
+            StackedBuilder(b, cfg.n_mem_blocks), cfg, "shared")
+        params["hybrid"] = app_params(StackedBuilder(b, cfg.n_attn_apps),
+                                      cfg, "hybrid")
     return params
+
+
+def mem_block_params(b, cfg, prefix: str) -> dict:
+    """One Zamba2 shared (memory) block: RMSNorm over concat(hidden,
+    embeddings), attention from that 2·d_model width, RMSNorm, gated MLP."""
+    D, H, KV, hd, F = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.hd, cfg.d_ff
+    D2 = 2 * D
+    return {
+        "in_norm": _norm_params(b, f"{prefix}.in_norm", D2),
+        "attn": {
+            "wq": b.leaf(f"{prefix}.wq", (D2, H, hd), ("embed", "heads", "head_dim")),
+            "wk": b.leaf(f"{prefix}.wk", (D2, KV, hd), ("embed", "kv_heads", "head_dim")),
+            "wv": b.leaf(f"{prefix}.wv", (D2, KV, hd), ("embed", "kv_heads", "head_dim")),
+            "wo": b.leaf(f"{prefix}.wo", (H, hd, D), ("heads", "head_dim", "embed")),
+        },
+        "ff_norm": _norm_params(b, f"{prefix}.ff_norm", D),
+        "mlp": mlp_params(b, cfg, f"{prefix}.mlp"),
+    }
+
+
+def app_params(b, cfg, prefix: str) -> dict:
+    """What one application of a shared block owns: the rank-r adapter of
+    its MLP's gate and up projections, and the linear into the layer."""
+    D, F, r = cfg.d_model, cfg.d_ff, cfg.adapter_rank
+    return {"ad_in": b.leaf(f"{prefix}.ad_in", (D, r), ("embed", None)),
+            "ad_gate": b.leaf(f"{prefix}.ad_gate", (r, F), (None, "ff")),
+            "ad_up": b.leaf(f"{prefix}.ad_up", (r, F), (None, "ff")),
+            "linear": b.leaf(f"{prefix}.linear", (D, D), ("embed", None))}
 
 
 # --------------------------------------------------------------------------- #
@@ -153,11 +189,76 @@ def _moe_block(cfg, p, x, positions, *, kv_cache=None, pos=None):
     return x + y, new_kv, aux
 
 
-def _ssm_block(cfg, p, x, cache=None):
-    h = rms_norm(x, p["ln"]["scale"], cfg.norm_eps)
+def _ssm_block(cfg, p, x, cache=None, t=None):
+    """A Mamba layer; ``t`` (hybrid) joins its input, not its residual."""
+    h = rms_norm(x if t is None else x + t, p["ln"]["scale"], cfg.norm_eps)
     block = mamba1_block if cfg.family == "ssm" else mamba2_block
     y, new_cache = block(cfg, p["mamba"], h, cache)
     return x + y, new_cache
+
+
+def mem_block(cfg, sp, ap, x, emb, positions, *, kv_cache=None, pos=None):
+    """One application of a Zamba2 shared block (``sp``) with its own
+    adapter and linear (``ap``): RMSNorm(concat(x, emb)) → attention
+    (scores scaled by (hd/2)^-0.5) → RMSNorm → gated MLP → linear; no
+    residual of its own.  → (what joins the layer's Mamba input, (k, v))."""
+    eps = cfg.norm_eps
+    h = rms_norm(jnp.concatenate([x, emb], axis=-1), sp["in_norm"]["scale"],
+                 eps)
+    q, k, v = qkv_project(cfg, sp["attn"], h, positions)
+    scale = (cfg.hd / 2) ** -0.5
+    if kv_cache is not None:
+        kc, vc = cache_update(*kv_cache, k, v, pos)
+        o = attend_decode(cfg, q, kc, vc, pos, scale=scale)
+        new_kv = (kc, vc)
+    else:
+        o = attend_prefill(cfg, q, k, v, causal=True, scale=scale)
+        new_kv = (k, v)
+    h = rms_norm(o_project(sp["attn"], o), sp["ff_norm"]["scale"], eps)
+    y = mlp(cfg, sp["mlp"], h, adapter=ap)
+    return jnp.einsum("bsd,de->bse", y, ap["linear"]), new_kv
+
+
+def _app(mem, a, n_mem_blocks):
+    """(shared block, application params) of application ``a`` (traced)."""
+    pick = lambda i: (lambda w: jax.lax.dynamic_index_in_dim(w, i, 0, False))
+    return (jax.tree.map(pick(a % n_mem_blocks), mem["shared"]),
+            jax.tree.map(pick(a), mem["hybrid"]))
+
+
+def hybrid_layer(cfg, p_i, a, x, emb, positions, mem, *, cache=None,
+                 akv=None, slot=None, pos=None):
+    """A layer of the hybrid family: a Mamba-2 layer whose input gains
+    shared-block application ``a``'s output (``a`` < 0: none, a plain
+    Mamba-2 layer).  ``mem``: the stacked ``{"shared", "hybrid"}`` params.
+    ``akv``: the (ak, av) K/V caches of every application, kept in slot
+    ``slot``: prefill (``pos`` None) writes this prompt's k, v there,
+    decode reads and extends them.  → (y, Mamba cache, akv)."""
+    def with_block(args):
+        x, akv = args
+        sp, ap = _app(mem, a, cfg.n_mem_blocks)
+        if akv is None:
+            return mem_block(cfg, sp, ap, x, emb, positions)[0], None
+        ak, av = akv
+        if pos is None:
+            t, (k, v) = mem_block(cfg, sp, ap, x, emb, positions)
+            pad = ((0, 0), (0, ak.shape[2] - k.shape[1]), (0, 0), (0, 0))
+            k, v = jnp.pad(k, pad), jnp.pad(v, pad)
+        else:
+            kv = tuple(jax.lax.dynamic_index_in_dim(c, slot, 0, False)
+                       for c in akv)
+            t, (k, v) = mem_block(cfg, sp, ap, x, emb, positions,
+                                  kv_cache=kv, pos=pos)
+        at = (slot, 0, 0, 0, 0)
+        return t, (jax.lax.dynamic_update_slice(ak, k[None].astype(ak.dtype), at),
+                   jax.lax.dynamic_update_slice(av, v[None].astype(av.dtype), at))
+
+    def without(args):
+        x, akv = args
+        return jnp.zeros_like(x), akv
+    t, akv = jax.lax.cond(a >= 0, with_block, without, (x, akv))
+    y, nc = _ssm_block(cfg, p_i, x, cache=cache, t=t)
+    return y, nc, akv
 
 
 # --------------------------------------------------------------------------- #
@@ -214,20 +315,16 @@ def trunk_train(cfg, params, x, positions):
         return x, 0.0
 
     if fam == "hybrid":
-        shared = params["shared"]
-        every = cfg.shared_attn_every
+        mem = {"shared": params["shared"], "hybrid": params["hybrid"]}
+        emb = x
 
         def body(c, xs):
-            p_i, i = xs
+            p_i, a = xs
             c = _shard_residual(c, cfg)
-            def with_attn(t):
-                y, _ = _attn_mlp_block(cfg, shared, t, positions)
-                return y
-            c = jax.lax.cond(i % every == 0, with_attn, lambda t: t, c)
-            y, _ = _ssm_block(cfg, p_i, c)
+            y, _, _ = hybrid_layer(cfg, p_i, a, c, emb, positions, mem)
             return _shard_residual(y, cfg), None
-        idx = jnp.arange(cfg.n_layers)
-        x, _ = jax.lax.scan(_maybe_remat(body, cfg), x, (layers, idx))
+        apps = jnp.asarray(cfg.attn_apps, jnp.int32)
+        x, _ = jax.lax.scan(_maybe_remat(body, cfg), x, (layers, apps))
         return x, 0.0
 
     raise ValueError(fam)
@@ -269,30 +366,19 @@ def trunk_prefill(cfg, params, x, positions, cache_len: int):
         return x, {**caches, "pos": jnp.int32(S)}
 
     if fam == "hybrid":
-        shared = params["shared"]
-        every = cfg.shared_attn_every
-        A = cfg.n_attn_apps
-        ak, av = (jnp.zeros((A, B, cache_len, cfg.n_kv_heads, cfg.hd),
-                            x.dtype) for _ in range(2))
+        mem = {"shared": params["shared"], "hybrid": params["hybrid"]}
+        emb = x
+        akv = tuple(jnp.zeros((cfg.n_attn_apps, B, cache_len, cfg.n_kv_heads,
+                               cfg.hd), x.dtype) for _ in range(2))
 
         def body(carry, xs):
-            c, ak, av = carry
-            p_i, i = xs
-
-            def with_attn(args):
-                c, ak, av = args
-                y, (k, v) = _attn_mlp_block(cfg, shared, c, positions)
-                k, v = pad_kv(k, v)
-                app = i // every
-                ak = jax.lax.dynamic_update_slice(ak, k[None], (app, 0, 0, 0, 0))
-                av = jax.lax.dynamic_update_slice(av, v[None], (app, 0, 0, 0, 0))
-                return y, ak, av
-            c, ak, av = jax.lax.cond(i % every == 0, with_attn,
-                                     lambda a: a, (c, ak, av))
-            y, nc = _ssm_block(cfg, p_i, c)
-            return (y, ak, av), nc
-        idx = jnp.arange(cfg.n_layers)
-        (x, ak, av), caches = jax.lax.scan(body, (x, ak, av), (layers, idx))
+            c, akv = carry
+            p_i, a = xs
+            y, nc, akv = hybrid_layer(cfg, p_i, a, c, emb, positions, mem,
+                                      akv=akv, slot=a)
+            return (y, akv), nc
+        apps = jnp.asarray(cfg.attn_apps, jnp.int32)
+        (x, (ak, av)), caches = jax.lax.scan(body, (x, akv), (layers, apps))
         return x, {**caches, "ak": ak, "av": av, "pos": jnp.int32(S)}
 
     raise ValueError(fam)
@@ -328,31 +414,19 @@ def trunk_decode(cfg, params, x, cache):
         return x, {**new, "pos": pos + 1}
 
     if fam == "hybrid":
-        shared = params["shared"]
-        every = cfg.shared_attn_every
+        mem = {"shared": params["shared"], "hybrid": params["hybrid"]}
+        emb = x
 
         def body(carry, xs):
-            c, ak, av = carry
-            p_i, cc, i = xs
-
-            def with_attn(args):
-                c, ak, av = args
-                app = i // every
-                k_i = jax.lax.dynamic_index_in_dim(ak, app, 0, keepdims=False)
-                v_i = jax.lax.dynamic_index_in_dim(av, app, 0, keepdims=False)
-                y, (k, v) = _attn_mlp_block(cfg, shared, c, positions,
-                                            kv_cache=(k_i, v_i), pos=pos)
-                ak = jax.lax.dynamic_update_slice(ak, k[None], (app, 0, 0, 0, 0))
-                av = jax.lax.dynamic_update_slice(av, v[None], (app, 0, 0, 0, 0))
-                return y, ak, av
-            c, ak, av = jax.lax.cond(i % every == 0, with_attn,
-                                     lambda a: a, (c, ak, av))
-            y, nc = _ssm_block(cfg, p_i, c, cache=cc)
-            return (y, ak, av), nc
+            c, akv = carry
+            p_i, cc, a = xs
+            y, nc, akv = hybrid_layer(cfg, p_i, a, c, emb, positions, mem,
+                                      cache=cc, akv=akv, slot=a, pos=pos)
+            return (y, akv), nc
         sub = {k: cache[k] for k in ("conv", "h")}
-        idx = jnp.arange(cfg.n_layers)
-        (x, ak, av), new = jax.lax.scan(body, (x, cache["ak"], cache["av"]),
-                                        (layers, sub, idx))
+        apps = jnp.asarray(cfg.attn_apps, jnp.int32)
+        (x, (ak, av)), new = jax.lax.scan(
+            body, (x, (cache["ak"], cache["av"])), (layers, sub, apps))
         return x, {**new, "ak": ak, "av": av, "pos": pos + 1}
 
     raise ValueError(fam)
@@ -509,3 +583,81 @@ def forward_decode(cfg, params, token, cache):
     x, new = trunk_decode(cfg, params, x, cache)
     x = final_hidden(cfg, params, x)
     return lm_logits(x, params["embed"], params.get("lm_head")), new
+
+
+# --------------------------------------------------------------------------- #
+# The model as a chain of blocks, for the split runtime
+# --------------------------------------------------------------------------- #
+@dataclass(frozen=True)
+class ChainBlock:
+    """One block of an ``LMChain``: ``apply(p, state) -> state``."""
+
+    apply: Callable
+
+
+class LMChain:
+    """A decoder as the chain of blocks that ``EdgePipeline`` and
+    ``Worker`` cut and run, as they do a ``CNNModel``: ``embed`` (token
+    ids (B, S) → state), one block per layer (state → state) and
+    ``head`` (state → last-position logits (B, V), float32).  The state
+    between blocks is ``{"residual": (B, S, D)}``; the hybrid family adds
+    ``"embed"``, the original embeddings its shared blocks read, so a hop
+    carries both.  Blocks are named as in ``arch_block_graph``."""
+
+    FAMILIES = ("ssm", "hybrid")
+
+    def __init__(self, cfg):
+        if cfg.family not in self.FAMILIES:
+            raise ValueError(f"no block chain for the {cfg.family!r} family")
+        self.cfg = cfg
+        self.name = cfg.name
+        self.blocks = [("embed", ChainBlock(self._embed)),
+                       *((f"layer{i:03d}", ChainBlock(self._layer))
+                         for i in range(cfg.n_layers)),
+                       ("head", ChainBlock(self._head))]
+
+    def block_params(self, params) -> list:
+        """Per-block params from ``build_params``'s stacked layout.  Every
+        application of one shared block holds the same arrays."""
+        cfg = self.cfg
+        at = lambda tree, i: jax.tree.map(lambda w: w[i], tree)
+        out: list = [{"table": params["embed"]["table"]}]
+        apps = cfg.attn_apps if cfg.family == "hybrid" else (-1,) * cfg.n_layers
+        shared = ([at(params["shared"], m) for m in range(cfg.n_mem_blocks)]
+                  if cfg.family == "hybrid" else [])
+        for i, a in enumerate(apps):
+            p = {"layer": at(params["layers"], i)}
+            if a >= 0:
+                p["shared"] = shared[a % cfg.n_mem_blocks]
+                p["hybrid"] = at(params["hybrid"], a)
+            out.append(p)
+        head = {"norm": params["final_norm"], "embed": params["embed"]}
+        if "lm_head" in params:
+            head["lm_head"] = params["lm_head"]
+        out.append(head)
+        return out
+
+    def apply(self, params, tokens):
+        """The whole chain: block params (``block_params``) → logits."""
+        x = tokens
+        for (_, blk), p in zip(self.blocks, params):
+            x = blk.apply(p, x)
+        return x
+
+    def _embed(self, p, tokens):
+        x = embed_lookup(p["table"], tokens)
+        return {"residual": x, "embed": x} if self.cfg.family == "hybrid" \
+            else {"residual": x}
+
+    def _layer(self, p, state):
+        cfg, x, t = self.cfg, state["residual"], None
+        if "shared" in p:
+            t, _ = mem_block(cfg, p["shared"], p["hybrid"], x,
+                             state["embed"], jnp.arange(x.shape[1]))
+        y, _ = _ssm_block(cfg, p["layer"], x, t=t)
+        return {**state, "residual": y}
+
+    def _head(self, p, state):
+        x = state["residual"][:, -1:]
+        h = rms_norm(x, p["norm"]["scale"], self.cfg.norm_eps)
+        return lm_logits(h, p["embed"], p.get("lm_head"))[:, 0]

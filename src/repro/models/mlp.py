@@ -28,12 +28,21 @@ def mlp_params(b: Builder, cfg, prefix: str, d_ff: int | None = None) -> dict:
     return p
 
 
-def mlp(cfg, p, x):
+def mlp(cfg, p, x, adapter=None):
+    """``adapter`` (Zamba2): a rank-r term ``(x @ ad_in) @ ad_gate``/``ad_up``
+    added to the gate and up projections."""
     up = jnp.einsum("bsd,df->bsf", x, p["w_up"])
+    if adapter is not None:
+        lo = jnp.einsum("bsd,dr->bsr", x, adapter["ad_in"])
+        up = up + jnp.einsum("bsr,rf->bsf", lo, adapter["ad_up"])
     up = shard(up, "batch", "seq", "ff")
     if cfg.gated_mlp:
         gate = jnp.einsum("bsd,df->bsf", x, p["w_gate"])
-        h = silu(gate) * up
+        if adapter is not None:
+            gate = gate + jnp.einsum("bsr,rf->bsf", lo, adapter["ad_gate"])
+        act = (jax.nn.gelu(gate, approximate=False) if cfg.mlp_act == "gelu"
+               else silu(gate))
+        h = act * up
     else:
         h = gelu(up)
     y = jnp.einsum("bsf,fd->bsd", h, p["w_down"])

@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from ..sharding.api import shard
-from .common import Builder, rms_norm, silu, softplus
+from .common import Builder, silu, softplus
 
 
 # --------------------------------------------------------------------------- #
@@ -153,9 +153,9 @@ def mamba1_block(cfg, p, x, cache=None):
 # --------------------------------------------------------------------------- #
 def mamba2_params(b: Builder, cfg, prefix: str) -> dict:
     D, di, N, K = cfg.d_model, cfg.d_inner, cfg.ssm_state, cfg.ssm_conv
-    H = cfg.ssm_heads
-    d_xbc = di + 2 * N
-    d_in = 2 * di + 2 * N + H
+    H, G = cfg.ssm_heads, cfg.ssm_ngroups
+    d_xbc = di + 2 * G * N
+    d_in = di + d_xbc + H
 
     def a_init(key, shape, dtype):
         return jnp.log(jnp.linspace(1.0, 16.0, H)).astype(dtype)
@@ -177,18 +177,20 @@ def mamba2_params(b: Builder, cfg, prefix: str) -> dict:
 
 def _ssd_chunk(cfg, dt, zlog, x, B_, C_, h0):
     """Chunked SSD.  dt: (B,S,H) input scale; zlog = dt·A ≤ 0 decay exponent;
-    x: (B,S,H,P); B_,C_: (B,S,N).  Returns (y (B,S,H,P), h_fin (B,H,P,N))."""
-    Bb, S, H, P = x.shape
+    x: (B,S,G,Hg,P), the heads in B/C groups; B_,C_: (B,S,G,N).
+    Returns (y (B,S,G,Hg,P), h_fin (B,G,Hg,P,N))."""
+    Bb, S, G, Hg, P = x.shape
     L = min(cfg.ssm_chunk, S)
     if S % L != 0:
         L = S
     nc = S // L
+    grouped = lambda t: t.reshape(*t.shape[:-1], G, Hg)     # (..,H)→(..,G,Hg)
 
     def chunk_step(h, xs):
-        dt_c, z_c, x_c, B_c, C_c = xs       # (B,L,H) ×2, (B,L,H,P), (B,L,N) ×2
+        dt_c, z_c, x_c, B_c, C_c = xs       # (B,L,H) ×2, (B,L,G,Hg,P), (B,L,G,N) ×2
         Scum = jnp.cumsum(z_c, axis=1)      # (B,L,H)
         # intra-chunk: att[b,t,s,h] = exp(S_t - S_s)·(C_t·B_s), s ≤ t
-        cb = jnp.einsum("btn,bsn->bts", C_c.astype(jnp.float32),
+        cb = jnp.einsum("btgn,bsgn->btsg", C_c.astype(jnp.float32),
                         B_c.astype(jnp.float32))
         dec = Scum[:, :, None, :] - Scum[:, None, :, :]      # (B,t,s,H)
         dec = shard(dec, "batch", None, None, "ssm_heads")
@@ -196,56 +198,72 @@ def _ssd_chunk(cfg, dt, zlog, x, B_, C_, h0):
         # mask *before* exp: exp of a positive upper-tri entry would inf
         # out and poison the backward pass with inf·0 NaNs.
         w = jnp.exp(jnp.where(tri, dec, -jnp.inf))
-        att = cb[..., None] * w                               # (B,t,s,H)
-        att = shard(att, "batch", None, None, "ssm_heads")
-        dtx = dt_c[..., None] * x_c.astype(jnp.float32)       # (B,L,H,P)
-        y = jnp.einsum("btsh,bshp->bthp", att, dtx)
+        att = cb[..., None] * grouped(w)                     # (B,t,s,G,Hg)
+        dtx = grouped(dt_c)[..., None] * x_c.astype(jnp.float32)
+        y = jnp.einsum("btsgh,bsghp->btghp", att, dtx)
         # carry-in: y_t += exp(S_t)·(C_t · h)
-        y = y + jnp.einsum("btn,bhpn->bthp", C_c.astype(jnp.float32),
-                           h) * jnp.exp(Scum)[..., None]
+        y = y + jnp.einsum("btgn,bghpn->btghp", C_c.astype(jnp.float32),
+                           h) * grouped(jnp.exp(Scum))[..., None]
         # new carry: h' = exp(S_L)·h + Σ_s exp(S_L - S_s) B_s ⊗ dtx_s
-        wL = jnp.exp(Scum[:, -1:, :] - Scum)                  # (B,L,H)
-        h_new = h * jnp.exp(Scum[:, -1])[..., None, None] + \
-            jnp.einsum("bsn,bshp,bsh->bhpn", B_c.astype(jnp.float32), dtx, wL)
+        wL = grouped(jnp.exp(Scum[:, -1:, :] - Scum))        # (B,L,G,Hg)
+        h_new = h * grouped(jnp.exp(Scum[:, -1]))[..., None, None] + \
+            jnp.einsum("bsgn,bsghp,bsgh->bghpn", B_c.astype(jnp.float32),
+                       dtx, wL)
         return h_new, y
 
     shape5 = lambda t: t.reshape(Bb, nc, L, *t.shape[2:]).swapaxes(0, 1)
     xs = (shape5(dt), shape5(zlog), shape5(x), shape5(B_), shape5(C_))
     h_fin, ys = jax.lax.scan(chunk_step, h0.astype(jnp.float32), xs)
-    y = ys.swapaxes(0, 1).reshape(Bb, S, H, P)
+    y = ys.swapaxes(0, 1).reshape(Bb, S, G, Hg, P)
     return y, h_fin
 
 
+def gated_group_norm(y, z, scale, groups: int, eps: float):
+    """Mamba-2's gated RMSNorm: ``y · silu(z)`` in float32, normalized
+    over each of ``groups`` equal slices of the last axis, then scaled."""
+    g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    g = g.reshape(*g.shape[:-1], groups, -1)
+    g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + eps)
+    return g.reshape(y.shape) * scale.astype(jnp.float32)
+
+
 def mamba2_block(cfg, p, x, cache=None):
-    """x: (B, S, D); cache {"conv": (B,K-1,d_xbc), "h": (B,H,P,N)}."""
+    """x: (B, S, D); cache {"conv": (B,K-1,d_xbc), "h": (B,H,P,N)}.  The
+    heads fall in ``cfg.ssm_ngroups`` groups, each group's heads reading
+    its own B and C."""
     B, S, D = x.shape
     di, N, H, P = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads, cfg.ssm_head_dim
+    G = cfg.ssm_ngroups
+    Hg = H // G
     zxbcdt = jnp.einsum("bsd,de->bse", x, p["in_proj"])
     zxbcdt = shard(zxbcdt, "batch", "seq", "d_inner")
-    z, xBC, dt_raw = jnp.split(zxbcdt, [di, 2 * di + 2 * N], axis=-1)
+    z, xBC, dt_raw = jnp.split(zxbcdt, [di, 2 * di + 2 * G * N], axis=-1)
     conv_in = cache["conv"] if cache is not None else None
     xBC, conv_out = causal_conv(xBC, p["conv_w"], p["conv_b"], conv_in)
     xBC = silu(xBC)
-    xr, B_, C_ = jnp.split(xBC, [di, di + N], axis=-1)
-    xh = xr.reshape(B, S, H, P)
+    xr, B_, C_ = jnp.split(xBC, [di, di + G * N], axis=-1)
+    xh = xr.reshape(B, S, G, Hg, P)
+    B_ = B_.reshape(B, S, G, N)
+    C_ = C_.reshape(B, S, G, N)
     A = -jnp.exp(p["A_log"].astype(jnp.float32))              # (H,)
     dt = softplus(dt_raw.astype(jnp.float32) + p["dt_bias"])  # (B,S,H)
+    dt = jnp.maximum(dt, cfg.ssm_dt_min)
     zlog = dt * A                                             # decay exponent
-    h0 = cache["h"] if cache is not None else jnp.zeros((B, H, P, N), jnp.float32)
+    h0 = (cache["h"] if cache is not None
+          else jnp.zeros((B, H, P, N), jnp.float32)).reshape(B, G, Hg, P, N)
 
     if S == 1 and cache is not None:
-        dA = jnp.exp(zlog[:, 0])                              # (B,H)
-        dtx = dt[:, 0, :, None] * xh[:, 0].astype(jnp.float32)
+        dA = jnp.exp(zlog[:, 0]).reshape(B, G, Hg)
+        dtx = dt[:, 0].reshape(B, G, Hg, 1) * xh[:, 0].astype(jnp.float32)
         h = h0 * dA[..., None, None] + \
-            jnp.einsum("bn,bhp->bhpn", B_[:, 0].astype(jnp.float32), dtx)
-        y = jnp.einsum("bn,bhpn->bhp", C_[:, 0].astype(jnp.float32), h)[:, None]
+            jnp.einsum("bgn,bghp->bghpn", B_[:, 0].astype(jnp.float32), dtx)
+        y = jnp.einsum("bgn,bghpn->bghp", C_[:, 0].astype(jnp.float32),
+                       h)[:, None]
         h_fin = h
     else:
         y, h_fin = _ssd_chunk(cfg, dt, zlog, xh, B_, C_, h0)
-    y = y + xh.astype(jnp.float32) * p["D"][:, None]
-    y = y.reshape(B, S, di)
-    y = rms_norm((y * silu(z).astype(jnp.float32)).astype(x.dtype), p["norm"],
-                 cfg.norm_eps)
-    out = jnp.einsum("bsc,cd->bsd", y, p["out_proj"])
+    y = y + xh.astype(jnp.float32) * p["D"].reshape(G, Hg, 1)
+    y = gated_group_norm(y.reshape(B, S, di), z, p["norm"], G, cfg.norm_eps)
+    out = jnp.einsum("bsc,cd->bsd", y.astype(x.dtype), p["out_proj"])
     out = shard(out, "batch", "seq", "embed")
-    return out, {"conv": conv_out, "h": h_fin}
+    return out, {"conv": conv_out, "h": h_fin.reshape(B, H, P, N)}

@@ -85,8 +85,23 @@ def _named(fn: Callable, name: str) -> Callable:
     return fn
 
 
+def place_once(tree, device):
+    """Every leaf of ``tree`` on ``device``, each distinct array once:
+    blocks that share weights (Zamba2's memory blocks, a tied embedding)
+    share one buffer on the device, whichever stages hold them."""
+    placed: dict[int, jax.Array] = {}
+
+    def put(a):
+        if id(a) not in placed:
+            placed[id(a)] = jax.device_put(a, device)
+        return placed[id(a)]
+    return jax.tree.map(put, tree)
+
+
 class Worker:
-    """One pipeline stage: executes blocks[lo:hi] of a CNNModel.
+    """One pipeline stage: executes blocks[lo:hi] of a model whose
+    ``blocks`` are ``(name, layer)`` pairs, ``layer.apply(p, x)`` — a
+    ``CNNModel``, or an ``LMChain`` whose ``x`` is a dict of tensors.
 
     ``cpu_clock`` attributes CPU time to this worker (default
     ``process_time`` — XLA:CPU executes on an internal pool, which a
@@ -275,6 +290,8 @@ class _ThreadEngine:
         self.pipe = pipe
         self.chan_groups: list[list[T.EmulatedChannel]] = self._open_chans()
         self.stage_workers: list[list[Worker]] = []
+        # the weights, on the device once for every stage and replica
+        self.params = place_once(pipe.params, jax.devices()[0])
         self._build_workers()
 
     def _open_chans(self) -> "list[list[T.EmulatedChannel]]":
@@ -323,7 +340,7 @@ class _ThreadEngine:
             for m in range(pipe.replicas[i]):
                 cached = pool[key].pop() if pool.get(key) else None
                 ws.append(cached or Worker(
-                    f"worker{i + 1}", pipe.model, pipe.params,
+                    f"worker{i + 1}", pipe.model, self.params,
                     bounds[i], bounds[i + 1], pipe.backends[i],
                     pace_s=pipe.stage_pace_s[i]))
             self.stage_workers.append(ws)
@@ -467,7 +484,7 @@ class _ThreadEngine:
                     w = self.stage_workers[i][m]
                     if (bounds[i], bounds[i + 1]) != (w.lo, w.hi):
                         self.stage_workers[i][m] = Worker(
-                            f"worker{i + 1}", pipe.model, pipe.params,
+                            f"worker{i + 1}", pipe.model, self.params,
                             bounds[i], bounds[i + 1], pipe.backends[i],
                             pace_s=pipe.stage_pace_s[i])
                     if codecs is not None and not last:

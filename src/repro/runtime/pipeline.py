@@ -120,7 +120,11 @@ def build_pipeline_params(cfg, b: Builder, pcfg: PipelineConfig) -> dict:
     pb = PipelineBuilder(b, pcfg, cfg.n_layers)
     params["layers"] = layer_params(cfg, pb)
     if cfg.family == "hybrid":
-        params["shared"] = _attn_block_params(b, cfg, "shared")
+        from ..models.lm import StackedBuilder, app_params, mem_block_params
+        params["shared"] = mem_block_params(
+            StackedBuilder(b, cfg.n_mem_blocks), cfg, "shared")
+        params["hybrid"] = app_params(StackedBuilder(b, cfg.n_attn_apps),
+                                      cfg, "hybrid")
     return params
 
 
@@ -174,8 +178,26 @@ def unpack_params(pipeline_layers, pcfg: PipelineConfig, n_layers: int):
 # --------------------------------------------------------------------------- #
 # Per-stage layer application (generic across families)
 # --------------------------------------------------------------------------- #
-def _layer_fn_train(cfg, p_i, x, positions, gidx, shared, enc_hidden):
-    from ..models.lm import _attn_mlp_block, _moe_block, _ssm_block, _dec_layer
+def _mem(params):
+    """The hybrid family's shared-block params, replicated on every pod."""
+    if "shared" not in params:
+        return None
+    return {"shared": params["shared"], "hybrid": params["hybrid"]}
+
+
+def _apps(cfg, l_max: int):
+    """Per global layer (padded by ``l_max``), its shared-block application
+    (-1: none); and per layer, the applications before it."""
+    apps = np.asarray((*cfg.attn_apps, *(-1,) * l_max), np.int32)
+    before = np.concatenate([[0], np.cumsum(apps >= 0)]).astype(np.int32)
+    return jnp.asarray(apps), jnp.asarray(before)
+
+
+def _layer_fn_train(cfg, p_i, x, positions, gidx, mem, side):
+    """``side``: what every layer also reads, per microbatch: the
+    encoder's output (encdec) or the original embeddings (hybrid)."""
+    from ..models.lm import (_attn_mlp_block, _moe_block, _ssm_block,
+                             _dec_layer, hybrid_layer)
     if cfg.family in ("dense", "vlm"):
         y, _ = _attn_mlp_block(cfg, p_i, x, positions)
         return y
@@ -186,29 +208,24 @@ def _layer_fn_train(cfg, p_i, x, positions, gidx, shared, enc_hidden):
         y, _ = _ssm_block(cfg, p_i, x)
         return y
     if cfg.family == "hybrid":
-        def with_attn(t):
-            y, _ = _attn_mlp_block(cfg, shared, t, positions)
-            return y
-        x = jax.lax.cond(gidx % cfg.shared_attn_every == 0, with_attn,
-                         lambda t: t, x)
-        y, _ = _ssm_block(cfg, p_i, x)
+        apps, _ = _apps(cfg, cfg.n_layers)
+        y, _, _ = hybrid_layer(cfg, p_i, apps[gidx], x, side, positions, mem)
         return y
     if cfg.family == "encdec":
-        y, _, _ = _dec_layer(cfg, p_i, x, enc_hidden, positions)
+        y, _, _ = _dec_layer(cfg, p_i, x, side, positions)
         return y
     raise ValueError(cfg.family)
 
 
-def _stage_apply(cfg, stage_layers, x, positions, start, count, shared,
-                 enc_hidden, l_max):
+def _stage_apply(cfg, stage_layers, x, positions, start, count, mem,
+                 side, l_max):
     """Run this pod's layer slice (padded to l_max) on x."""
     from ..models.lm import _shard_residual
 
     def body(c, xs):
         p_i, li = xs
         c = _shard_residual(c, cfg)
-        y = _layer_fn_train(cfg, p_i, c, positions, start + li, shared,
-                            enc_hidden)
+        y = _layer_fn_train(cfg, p_i, c, positions, start + li, mem, side)
         c = jnp.where(li < count, y, c)
         return _shard_residual(c, cfg), None
     fn = jax.checkpoint(body) if cfg.remat else body
@@ -257,9 +274,11 @@ def make_pipeline_train_step(cfg, pcfg: PipelineConfig, opt: OptConfig,
         if enc_hidden is not None:
             F = enc_hidden.shape[1]
             enc_mb32 = enc_hidden.reshape(M, mb, F, D).astype(jnp.float32)
+        elif cfg.family == "hybrid":
+            enc_mb32 = x_mb32             # the original embeddings
         shared32 = jax.tree.map(
             lambda a: a.astype(jnp.float32) if a.dtype == jnp.bfloat16 else a,
-            params.get("shared"))
+            _mem(params))
 
         def pipeline(stage_layers, x_mb32, enc_mb32, shared32):
             sid = jax.lax.axis_index("pod")
@@ -343,7 +362,8 @@ def make_pipeline_prefill_step(cfg, pcfg: PipelineConfig, mesh,
         clen = cache_len or S
         layers = params["dec_layers"] if cfg.family == "encdec" \
             else params["layers"]
-        shared = params.get("shared")
+        mem = _mem(params)
+        side = x if cfg.family == "hybrid" else enc_hidden
         starts = jnp.asarray(starts_np)
         counts = jnp.asarray(counts_np)
 
@@ -356,7 +376,8 @@ def make_pipeline_prefill_step(cfg, pcfg: PipelineConfig, mesh,
                 buf, cache = carry
                 y, new_cache = _stage_prefill(cfg, stage_layers, buf,
                                               positions, start, count,
-                                              shared, enc_hidden, l_max, clen)
+                                              mem, side, l_max, clen,
+                                              n_attn_slots(cfg, pcfg))
                 # commit this stage's cache only on its own tick (the tick
                 # when its buffer holds real data: t == stage id)
                 cache = jax.tree.map(
@@ -365,7 +386,8 @@ def make_pipeline_prefill_step(cfg, pcfg: PipelineConfig, mesh,
                 nxt = jax.lax.ppermute(y, "pod", perm) if K > 1 else y
                 return (nxt, cache), y
 
-            cache0 = _empty_stage_cache(cfg, l_max, B, clen, x.dtype)
+            cache0 = _empty_stage_cache(cfg, l_max, B, clen, x.dtype,
+                                        n_attn_slots(cfg, pcfg))
             (_, cache), ys = jax.lax.scan(tick, (x, cache0), jnp.arange(K))
             last = ys[K - 1]
             return jax.tree.map(lambda c: c[None], (last, cache))
@@ -394,7 +416,7 @@ def make_pipeline_decode_step(cfg, pcfg: PipelineConfig, mesh):
         x = embed_lookup(params["embed"]["table"], token)
         layers = params["dec_layers"] if cfg.family == "encdec" \
             else params["layers"]
-        shared = params.get("shared")
+        mem = _mem(params)
         starts = jnp.asarray(starts_np)
         counts = jnp.asarray(counts_np)
         kv = {k: v for k, v in cache.items() if k != "pos"}
@@ -408,7 +430,7 @@ def make_pipeline_decode_step(cfg, pcfg: PipelineConfig, mesh):
             def tick(carry, t):
                 buf, cache = carry
                 y, new_cache = _stage_decode(cfg, stage_layers, buf, cache,
-                                             pos, start, count, shared, l_max)
+                                             pos, start, count, mem, x, l_max)
                 cache = jax.tree.map(
                     lambda old, new: jnp.where(t == sid, new, old),
                     cache, new_cache)
@@ -429,8 +451,10 @@ def make_pipeline_decode_step(cfg, pcfg: PipelineConfig, mesh):
     return decode
 
 
-def _stage_decode(cfg, stage_layers, x, cache, pos, start, count, shared,
-                  l_max):
+def _stage_decode(cfg, stage_layers, x, cache, pos, start, count, mem,
+                  emb, l_max):
+    """``emb``: the token's embedding, which the hybrid family's shared
+    blocks read beside ``x``."""
     from ..models.lm import _attn_mlp_block, _moe_block, _ssm_block
     positions = pos[None]
 
@@ -461,48 +485,36 @@ def _stage_decode(cfg, stage_layers, x, cache, pos, start, count, shared,
 
     if cfg.family == "hybrid":
         return _stage_decode_hybrid(cfg, stage_layers, x, cache, pos, start,
-                                    count, shared, l_max)
+                                    count, mem, emb, l_max)
     x, caches = jax.lax.scan(body, x, (stage_layers, cache,
                                        jnp.arange(l_max)))
     return x, caches
 
 
 def _stage_decode_hybrid(cfg, stage_layers, x, cache, pos, start, count,
-                         shared, l_max):
-    from ..models.lm import _attn_mlp_block, _ssm_block
+                         mem, emb, l_max):
+    from ..models.lm import hybrid_layer
     positions = pos[None]
-    every = cfg.shared_attn_every
-    ak, av = cache["ak"], cache["av"]
+    apps, before = _apps(cfg, l_max)
+    first = before[start]                 # this stage's first K/V slot
     ssm_cache = {k: cache[k] for k in ("conv", "h")}
 
     def body(carry, xs):
-        c, ak, av = carry
+        c, akv = carry
         p_i, cc, li = xs
-        gidx = start + li
-        slot = gidx // every - start // every
-
-        def with_attn(args):
-            c, ak, av = args
-            k_i = jax.lax.dynamic_index_in_dim(ak, slot, 0, keepdims=False)
-            v_i = jax.lax.dynamic_index_in_dim(av, slot, 0, keepdims=False)
-            y, (k, v) = _attn_mlp_block(cfg, shared, c, positions,
-                                        kv_cache=(k_i, v_i), pos=pos)
-            ak = jax.lax.dynamic_update_slice(ak, k[None], (slot, 0, 0, 0, 0))
-            av = jax.lax.dynamic_update_slice(av, v[None], (slot, 0, 0, 0, 0))
-            return y, ak, av
-        c2, ak, av = jax.lax.cond((gidx % every == 0) & (li < count),
-                                  with_attn, lambda a: a, (c, ak, av))
-        y, nc = _ssm_block(cfg, p_i, c2, cache=cc)
+        a = jnp.where(li < count, apps[start + li], -1)
+        y, nc, akv = hybrid_layer(cfg, p_i, a, c, emb, positions, mem,
+                                  cache=cc, akv=akv, slot=a - first, pos=pos)
         y = jnp.where(li < count, y, c)
-        return (y, ak, av), nc
+        return (y, akv), nc
 
-    (x, ak, av), new_ssm = jax.lax.scan(body, (x, ak, av),
-                                        (stage_layers, ssm_cache,
-                                         jnp.arange(l_max)))
+    (x, (ak, av)), new_ssm = jax.lax.scan(
+        body, (x, (cache["ak"], cache["av"])),
+        (stage_layers, ssm_cache, jnp.arange(l_max)))
     return x, {**new_ssm, "ak": ak, "av": av}
 
 
-def _empty_stage_cache(cfg, l_max, B, clen, dtype):
+def _empty_stage_cache(cfg, l_max, B, clen, dtype, n_slots: int = 0):
     KVh, hd = cfg.n_kv_heads, cfg.hd
     if cfg.family == "encdec":
         z = jnp.zeros((l_max, B, clen, KVh, hd), dtype)
@@ -515,8 +527,8 @@ def _empty_stage_cache(cfg, l_max, B, clen, dtype):
         return {"conv": jnp.zeros((l_max, B, cfg.ssm_conv - 1, cfg.d_inner), dtype),
                 "h": jnp.zeros((l_max, B, cfg.d_inner, cfg.ssm_state), jnp.float32)}
     if cfg.family == "hybrid":
-        d_xbc = cfg.d_inner + 2 * cfg.ssm_state
-        ns = n_attn_slots(cfg, l_max)
+        d_xbc = cfg.d_inner + 2 * cfg.ssm_ngroups * cfg.ssm_state
+        ns = n_slots
         return {"conv": jnp.zeros((l_max, B, cfg.ssm_conv - 1, d_xbc), dtype),
                 "h": jnp.zeros((l_max, B, cfg.ssm_heads, cfg.ssm_head_dim,
                                 cfg.ssm_state), jnp.float32),
@@ -525,15 +537,19 @@ def _empty_stage_cache(cfg, l_max, B, clen, dtype):
     raise ValueError(cfg.family)
 
 
-def n_attn_slots(cfg, l_max: int) -> int:
-    """Shared-attention KV slots per pipeline stage (slot-compressed: one
-    per application site, not one per layer)."""
-    return l_max // cfg.shared_attn_every + 2
+def n_attn_slots(cfg, pcfg: PipelineConfig) -> int:
+    """Shared-block K/V slots per pipeline stage: one per application in
+    the stage that holds the most."""
+    starts, counts, _ = pcfg.layout(cfg.n_layers)
+    apps = np.asarray(cfg.attn_apps) >= 0
+    return max(1, max(int(apps[a:a + n].sum()) for a, n in zip(starts, counts)))
 
 
-def _stage_prefill(cfg, stage_layers, x, positions, start, count, shared,
-                   enc_hidden, l_max, clen):
-    """Apply the stage's layers, returning per-layer caches (padded)."""
+def _stage_prefill(cfg, stage_layers, x, positions, start, count, mem,
+                   side, l_max, clen, n_slots: int = 0):
+    """Apply the stage's layers, returning per-layer caches (padded).
+    ``side``: the encoder's output (encdec) or the original embeddings
+    (hybrid)."""
     from ..models.lm import _attn_mlp_block, _moe_block, _ssm_block, _dec_layer
     B, S, D = x.shape
 
@@ -554,7 +570,7 @@ def _stage_prefill(cfg, stage_layers, x, positions, start, count, shared,
             y, (k, v), _ = _moe_block(cfg, p_i, c, positions)
             cache_i = dict(zip(("k", "v"), pad_kv(k, v)))
         elif cfg.family == "encdec":
-            y, (k, v), (ck, cv) = _dec_layer(cfg, p_i, c, enc_hidden, positions)
+            y, (k, v), (ck, cv) = _dec_layer(cfg, p_i, c, side, positions)
             k, v = pad_kv(k, v)
             cache_i = {"k": k, "v": v, "ck": ck, "cv": cv}
         elif cfg.family == "ssm":
@@ -567,46 +583,32 @@ def _stage_prefill(cfg, stage_layers, x, positions, start, count, shared,
 
     if cfg.family == "hybrid":
         return _stage_prefill_hybrid(cfg, stage_layers, x, positions, start,
-                                     count, shared, l_max, clen)
+                                     count, mem, side, l_max, clen, n_slots)
     x, caches = jax.lax.scan(body, x, (stage_layers, jnp.arange(l_max)))
     return x, caches
 
 
 def _stage_prefill_hybrid(cfg, stage_layers, x, positions, start, count,
-                          shared, l_max, clen):
-    """Hybrid stage prefill with slot-compressed shared-attention caches:
-    ak/av hold one (B, clen, KV, hd) slot per application site in this
-    stage; ssm caches stay per-layer via scan ys."""
-    from ..models.lm import _attn_mlp_block, _ssm_block
-    B, S, D = x.shape
-    ns = n_attn_slots(cfg, l_max)
-    every = cfg.shared_attn_every
-    pad = clen - S
-    ak = jnp.zeros((ns, B, clen, cfg.n_kv_heads, cfg.hd), x.dtype)
-    av = jnp.zeros_like(ak)
+                          mem, emb, l_max, clen, ns):
+    """Hybrid stage prefill with slot-compressed shared-block caches: ak/av
+    hold one (B, clen, KV, hd) slot per application in this stage; ssm
+    caches stay per-layer via scan ys."""
+    from ..models.lm import hybrid_layer
+    B = x.shape[0]
+    apps, before = _apps(cfg, l_max)
+    first = before[start]
+    akv = tuple(jnp.zeros((ns, B, clen, cfg.n_kv_heads, cfg.hd), x.dtype)
+                for _ in range(2))
 
     def body(carry, xs):
-        c, ak, av = carry
+        c, akv = carry
         p_i, li = xs
-        gidx = start + li
-        slot = gidx // every - start // every
-
-        def with_attn(args):
-            c, ak, av = args
-            y, (k, v) = _attn_mlp_block(cfg, shared, c, positions)
-            if pad:
-                z = jnp.zeros((B, pad, *k.shape[2:]), k.dtype)
-                k = jnp.concatenate([k, z], 1)
-                v = jnp.concatenate([v, z], 1)
-            ak = jax.lax.dynamic_update_slice(ak, k[None], (slot, 0, 0, 0, 0))
-            av = jax.lax.dynamic_update_slice(av, v[None], (slot, 0, 0, 0, 0))
-            return y, ak, av
-        c2, ak, av = jax.lax.cond((gidx % every == 0) & (li < count),
-                                  with_attn, lambda a: a, (c, ak, av))
-        y, cc = _ssm_block(cfg, p_i, c2)
+        a = jnp.where(li < count, apps[start + li], -1)
+        y, cc, akv = hybrid_layer(cfg, p_i, a, c, emb, positions, mem,
+                                  akv=akv, slot=a - first)
         y = jnp.where(li < count, y, c)
-        return (y, ak, av), cc
+        return (y, akv), cc
 
-    (x, ak, av), caches = jax.lax.scan(body, (x, ak, av),
-                                       (stage_layers, jnp.arange(l_max)))
+    (x, (ak, av)), caches = jax.lax.scan(body, (x, akv),
+                                         (stage_layers, jnp.arange(l_max)))
     return x, {**caches, "ak": ak, "av": av}

@@ -89,7 +89,8 @@ class TransferRecord(NamedTuple):
     hop codec is active) — the number link estimators fit bandwidth
     against and radio energy charges for.  ``raw_bytes`` is the
     pre-codec tensor size (-1 in unpacked legacy tuples; ``record``
-    normalizes it to ``nbytes``)."""
+    normalizes it to ``nbytes``).  Bytes by tensor, where a hop carries
+    named tensors, go to ``HopObservations.tensor_bytes``."""
 
     nbytes: int
     elapsed_s: float
@@ -331,15 +332,22 @@ class HopObservations:
         # pre-codec bytes (== total_bytes on uncoded hops): the
         # raw-vs-wire gap is the codec's realized saving
         self.total_raw_bytes: int = 0
+        # lifetime pre-codec bytes by tensor name: an LM stage's hop
+        # carries its ``residual`` and ``embed``, a CNN's one
+        # ``activation`` (emulated hops; process hops leave it empty)
+        self.tensor_bytes: dict[str, int] = {}
 
     def record(self, nbytes: int, elapsed_s: float, t_s: float,
-               raw_bytes: int = -1) -> TransferRecord:
+               raw_bytes: int = -1,
+               tensors: tuple[tuple[str, int], ...] = ()) -> TransferRecord:
         rec = TransferRecord(int(nbytes), float(elapsed_s), float(t_s),
                              int(raw_bytes) if raw_bytes >= 0 else int(nbytes))
         with self._lock:
             self.observations.append(rec)
             self.total_bytes += rec.nbytes
             self.total_raw_bytes += rec.raw_bytes
+            for name, n in tensors:
+                self.tensor_bytes[name] = self.tensor_bytes.get(name, 0) + n
             if rec.nbytes > 0:
                 self.total_transfers += 1
                 self.total_elapsed_s += rec.elapsed_s
@@ -476,7 +484,8 @@ class EmulatedChannel(Channel):
         self._rng = np.random.default_rng(hop.seed)
         self._q: queue.Queue = queue.Queue(maxsize=max(hop.depth, 1))
 
-    def emulate(self, nbytes: int, raw_bytes: int = -1) -> float:
+    def emulate(self, nbytes: int, raw_bytes: int = -1,
+                tensors: tuple[tuple[str, int], ...] = ()) -> float:
         """Inject the modeled wire delay for ``nbytes`` and record it."""
         t = self._clock()
         if isinstance(self.link, LinkTrace):
@@ -484,15 +493,28 @@ class EmulatedChannel(Channel):
         else:
             dt = self.link.transfer_time(nbytes)
         time.sleep(dt)
-        self.record(nbytes, dt, t, raw_bytes=raw_bytes)
+        self.record(nbytes, dt, t, raw_bytes=raw_bytes, tensors=tensors)
         return dt
 
     def _roundtrip(self, payload):
         """Apply the hop codec's exact wire transform in place of real
         packing: the next stage computes on the degraded tensor, so
-        emulated runs carry the codec's accuracy cost end to end.
-        → (wire bytes, raw bytes, decoded payload)."""
-        ids = dict(seq=current_seq(), hop=self.hop.index)
+        emulated runs carry the codec's accuracy cost end to end.  A
+        dict payload is a set of named tensors, each crossing on its own.
+        → (wire bytes, raw bytes, decoded payload, bytes by tensor)."""
+        if isinstance(payload, dict):
+            parts = {k: self._roundtrip_one(v, k) for k, v in payload.items()}
+            return (sum(p[0] for p in parts.values()),
+                    sum(p[1] for p in parts.values()),
+                    {k: p[2] for k, p in parts.items()},
+                    tuple((k, p[1]) for k, p in parts.items()))
+        wire, raw, out = self._roundtrip_one(payload, "activation")
+        return wire, raw, out, (("activation", raw),)
+
+    def _roundtrip_one(self, payload, tensor: str):
+        """→ (wire bytes, raw bytes, decoded tensor)."""
+        ids = dict(seq=current_seq(), hop=self.hop.index, tensor=tensor,
+                   bytes=getattr(payload, "nbytes", -1))
         with TraceAnnotation("hop.d2h", **ids):
             host = np.asarray(payload)
         raw = host.size * host.dtype.itemsize
@@ -509,12 +531,13 @@ class EmulatedChannel(Channel):
 
     def send(self, payload=None, kind: int = BATCH):
         if kind == BATCH:
+            tensors: tuple[tuple[str, int], ...] = ()
             if self.hop.framing == "pickle":
                 buf = _Serializer.dumps(payload)
                 nbytes, raw, out = len(buf), len(buf), _Serializer.loads(buf)
             else:
-                nbytes, raw, out = self._roundtrip(payload)
-            dt = self.emulate(nbytes, raw_bytes=raw)
+                nbytes, raw, out, tensors = self._roundtrip(payload)
+            dt = self.emulate(nbytes, raw_bytes=raw, tensors=tensors)
             with TraceAnnotation("hop.put_wait", seq=current_seq(),
                                  hop=self.hop.index):
                 self._q.put((kind, out))
@@ -524,7 +547,7 @@ class EmulatedChannel(Channel):
                      or hasattr(payload, "dtype"))):
             # round-trip (no delay): warms the codec's jitted kernels and
             # hands downstream a representative degraded exemplar
-            _, _, payload = self._roundtrip(payload)
+            _, _, payload, _ = self._roundtrip(payload)
             self._q.put((kind, payload))
             return None
         if kind == PROBE:

@@ -154,8 +154,8 @@ def test_knee_on_front():
 def test_blockgraph_cut_bytes_and_shared_groups():
     blocks = (
         Block("a", 1e6, 100, out_bytes=10),
-        Block("b", 1e6, 200, out_bytes=20, shared_group="s"),
-        Block("c", 1e6, 200, out_bytes=30, shared_group="s"),
+        Block("b", 1e6, 20, out_bytes=20, shared_group="s", shared_bytes=200),
+        Block("c", 1e6, 30, out_bytes=30, shared_group="s", shared_bytes=200),
         Block("d", 1e6, 50, out_bytes=40, broadcast_bytes=7),
         Block("e", 1e6, 60, out_bytes=50),
     )
@@ -164,7 +164,9 @@ def test_blockgraph_cut_bytes_and_shared_groups():
     assert g.cut_bytes(2) == 20
     assert g.cut_bytes(5) == 3
     assert g.cut_bytes(5 - 1) == 40 + 7  # broadcast edge crosses later cuts...
-    # shared group counted once globally and once per segment
-    assert g.total_weight_bytes == 100 + 200 + 50 + 60
-    assert g.segment_weight_bytes(1, 3) == 200
+    # a block's own weights always count; its shared group's once
+    # globally and once per segment
+    assert g.total_weight_bytes == 100 + 20 + 30 + 200 + 50 + 60
+    assert g.segment_weight_bytes(1, 3) == 20 + 30 + 200
+    assert g.segment_weight_bytes(2, 4) == 30 + 200 + 50
     assert g.segment_weight_bytes(0, 5) == g.total_weight_bytes

@@ -117,3 +117,26 @@ def test_resnet50_stage_compiles_for_one_chip(chip, resnet50_stages, stage):
     assert 0 < used < 16e9
     out = jax.eval_shape(worker.fn, worker.params, x)
     assert out.shape[0] == BATCH and np.dtype(out.dtype) == np.float32
+
+
+def test_zamba2_hybrid_layer_compiles_for_one_chip(chip):
+    """One hybrid layer of Zamba2-7B (a shared attention+MLP block over
+    the 7168-wide concat, then a Mamba-2 layer) at its published widths,
+    as the split runtime runs it on 4 prompts of 2048 tokens."""
+    import repro.configs as configs
+    from repro.models import lm
+    from repro.models.common import InitBuilder
+
+    cfg = configs.get("zamba2-7b").replace(n_layers=7)
+    chain = lm.LMChain(cfg)
+    shapes = jax.eval_shape(lambda k: chain.block_params(
+        lm.build_params(cfg, InitBuilder(k, jnp.bfloat16))),
+        jax.random.PRNGKey(0))
+    i = 1 + cfg.hybrid_layer_ids[0]                 # after the embed block
+    p = jax.tree.map(lambda a: _spec(a.shape, a.dtype, chip), shapes[i])
+    assert "shared" in p
+    act = _spec((4, 2048, cfg.d_model), jnp.bfloat16, chip)
+    compiled = jax.jit(chain.blocks[i][1].apply).lower(
+        p, {"residual": act, "embed": act}).compile()
+    mem = compiled.memory_analysis()
+    assert 0 < mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
