@@ -315,11 +315,11 @@ class Forward:
             x[:, -1], n.astype(jnp.float32), eps) @ emb.astype(jnp.float32).T)
         self.embed = jax.jit(lambda emb, t: emb[t].astype(jnp.float32))
 
-    def __call__(self, w: dict, tokens):
-        """tokens (B, S) → last-position logits (B, V), float32."""
+    def layers(self, w: dict, x, e, lo: int, hi: int):
+        """Layers ``lo``..``hi - 1`` on the residual stream ``x``, the
+        shared blocks reading the embeddings ``e``."""
         d = self.d
-        x = e = self.embed(w["embed"], tokens)
-        for i in range(d["L"]):
+        for i in range(lo, hi):
             p = f"layers.{i}."
             lw = {k[len(p):]: v for k, v in w.items() if k.startswith(p)}
             t = jnp.zeros_like(x)
@@ -332,6 +332,12 @@ class Forward:
                       if k.startswith(f"hybrid.{a}.")}
                 t = self.shared(ws, wa, x, e)
             x = self.layer(lw, x, t)
+        return x
+
+    def __call__(self, w: dict, tokens):
+        """tokens (B, S) → last-position logits (B, V), float32."""
+        x = e = self.embed(w["embed"], tokens)
+        x = self.layers(w, x, e, 0, self.d["L"])
         return self.head(w["embed"], w["final_norm"], x)
 
 

@@ -12,9 +12,13 @@ configuration gives the model in its published keys.
 Weights are the reference's own, from the seed, handed to the program
 in its block layout; the reference also gives the FLOPs charged per
 prompt (``flops_per_item``).  ``check`` runs the plain reference over
-every prompt of the pool once and compares every delivered row.
+every prompt of the pool once and compares every delivered row; then it
+compares what crossed each cut, in the micro-batches of set-up, with the
+reference at that cut (``compare_cuts``).
 """
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
@@ -91,6 +95,7 @@ class System(split_serving.System):
                    for i in range(int(traffic["tenants"]))]
         self.tenant_names = [t.name for t in tenants]
         self.gateway = Gateway(self.pipe, tenants, max_batch=self.max_batch)
+        self.taps = StageInputs(self.pipe.workers)
 
     # -- counters --------------------------------------------------------- #
     def hop_counts(self) -> list[tuple[dict, int]]:
@@ -105,7 +110,78 @@ class System(split_serving.System):
         fwd = self.ref.Forward(self.config)
         refs = np.concatenate([np.asarray(fwd(self.weights, pool[i:i + 1]))
                                for i in range(len(pool))])
-        return compare_rows(refs, answers, limits)
+        return (compare_rows(refs, answers, limits)
+                + compare_cuts(fwd, self.weights, self.cell["cuts"],
+                               self.taps.taken, limits))
+
+
+class StageInputs:
+    """Host copies of what each stage was given, for the micro-batches
+    run while ``armed``: the token ids at the first stage, at each later
+    one the state its hop delivered.  ``taken[k]`` lists stage k's, in
+    the order the batches ran (one replica per stage keeps it)."""
+
+    def __init__(self, workers):
+        self.armed = False
+        self.taken: list[list] = [[] for _ in workers]
+        for k, w in enumerate(workers):
+            w.run = self._tap(k, w.run)
+
+    def _tap(self, k, run):
+        def tapped(x):
+            if self.armed:
+                self.taken[k].append(jax.tree.map(np.asarray, x))
+            return run(x)
+        return tapped
+
+
+def compare_cuts(fwd, weights: dict, cuts, taken: list, limits: dict) -> list:
+    """What crossed each cut against the reference at that cut, over the
+    batches in ``taken`` (``StageInputs.taken``; cut ``c`` lies after
+    block ``c - 1``: the embedding, then layers ``0..c - 2``).
+
+    * ``cut_embed_err``: the largest error of the original embeddings
+      over the largest reference one; the lookup is exact in bfloat16,
+      so a sound hop reads 0.
+    * ``embed_effect_lost``: how much of what the embeddings add through
+      the shared blocks is missing from the residual stream at the
+      cuts: the program's deviation from the reference, projected on
+      the change that dropping the embeddings from the shared blocks'
+      input makes (reference float32 both ways), as a share of that
+      change, absolute; 0 where the program carries their whole
+      effect, 1 where its shared blocks ignore them.  Rounding noise is
+      nearly orthogonal to that change over millions of numbers.
+
+    A cut with nothing taken reads +inf in both.  → [(name, reading,
+    limit)]."""
+    if not cuts:
+        return []
+    n = len(taken[0])
+    if n == 0 or any(len(t) != n for t in taken[:len(cuts) + 1]):
+        return [("cut_embed_err", math.inf, limits["cut_embed_err"]),
+                ("embed_effect_lost", math.inf, limits["embed_effect_lost"])]
+    emb_err, num, den = 0.0, 0.0, 0.0
+    for j in range(n):
+        tokens = taken[0][j]
+        e = fwd.embed(weights["embed"], jnp.asarray(tokens))
+        x, x0, none, lo = e, e, jnp.zeros_like(e), 0
+        for k, c in enumerate(cuts, 1):
+            x = fwd.layers(weights, x, e, lo, c - 1)
+            x0 = fwd.layers(weights, x0, none, lo, c - 1)
+            lo = c - 1
+            got = taken[k][j]
+            want_e = np.asarray(e)
+            emb_err = max(emb_err, float(
+                np.max(np.abs(got["embed"].astype(np.float32) - want_e))
+                / max(float(np.max(np.abs(want_e))), 1e-30)))
+            ref = np.asarray(x, np.float64)
+            dev = got["residual"].astype(np.float64) - ref
+            drop = np.asarray(x0, np.float64) - ref
+            num += float(np.vdot(dev, drop))
+            den += float(np.vdot(drop, drop))
+    lost = abs(num) / den if den > 0 else math.inf
+    return [("cut_embed_err", emb_err, limits["cut_embed_err"]),
+            ("embed_effect_lost", lost, limits["embed_effect_lost"])]
 
 
 def hop_mb_per_batch(before: list, after: list) -> float | None:

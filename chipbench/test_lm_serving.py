@@ -1,8 +1,9 @@
 """The Zamba2 serving cell at a small size on the CPU: its prompt traffic
 offers the same work on every seed from one pool, a run through the
 harness is correct on the sound path and not correct with weights
-rounded to float8 or the last stage broken, and the hop counter reads
-both tensors of every cut."""
+rounded to float8, the last stage broken, a hop that zeroes the
+original embeddings, or shared blocks that ignore them, and the hop
+counter reads both tensors of every cut."""
 import json
 import subprocess
 import sys
@@ -48,8 +49,23 @@ def _run(fault=None, monkeypatch=None):
     import jax.numpy as jnp
     from chipbench.reference import zamba2 as zref
     from chipbench.systems import lm_split_serving as lss
-    from repro.runtime import edge
-    if fault == "float8_weights":
+    from repro.models import lm
+    from repro.runtime import edge, transport
+    if fault == "hop_zeroes_embed":
+        hop = transport.EmulatedChannel._roundtrip_one
+
+        def zeroing(self, payload, tensor):
+            wire, raw, out = hop(self, payload, tensor)
+            return wire, raw, (jnp.zeros_like(out) if tensor == "embed"
+                               else out)
+        monkeypatch.setattr(transport.EmulatedChannel, "_roundtrip_one",
+                            zeroing)
+    elif fault == "shared_blocks_skip_embed":
+        block = lm.mem_block
+        monkeypatch.setattr(lm, "mem_block", lambda cfg, sp, ap, x, emb, *a,
+                            **kw: block(cfg, sp, ap, x, jnp.zeros_like(emb),
+                                        *a, **kw))
+    elif fault == "float8_weights":
         orig = zref.program_params
         monkeypatch.setattr(zref, "program_params",
                             lambda w, c: lss.quantized_weights(orig(w, c)))
@@ -66,11 +82,16 @@ def _run(fault=None, monkeypatch=None):
                  require_chip=False, cell_overrides=SMALL)
 
 
-@pytest.mark.parametrize("fault", [None, "float8_weights", "last_stage_rows"])
-def test_correct_only_on_the_sound_path(monkeypatch, fault):
+@pytest.mark.parametrize("fault,caught_by", [
+    (None, None), ("float8_weights", "row_rel_err"),
+    ("last_stage_rows", "row_rel_err"), ("hop_zeroes_embed", "cut_embed_err"),
+    ("shared_blocks_skip_embed", "embed_effect_lost")])
+def test_correct_only_on_the_sound_path(monkeypatch, fault, caught_by):
     result, checks = _run(fault, monkeypatch)
     assert result["correct"] is (fault is None), checks
     assert result["failed"] == 0
+    over = {n for n, v, lim in checks if not v <= lim}
+    assert (caught_by in over) if fault else not over, checks
 
 
 def test_hop_counter_reads_both_tensors_of_every_cut():

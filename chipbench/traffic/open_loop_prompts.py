@@ -29,16 +29,20 @@ def prompt_pool(seed: int, n: int, seq: int, vocab: int) -> np.ndarray:
 def prepare(sut, spec: dict, seed: int, seconds: float) -> None:
     """Set-up: the prompt pool from the seed, then one request of every
     prompt count through the gateway, so the window's first requests
-    find every path warm; then the hop counters' starting reading."""
+    find every path warm, with each stage's input copied to the host
+    for the check (``StageInputs``); then the hop counters' starting
+    reading."""
     traffic = spec["traffic"]
     sut.pool = prompt_pool(seed, int(traffic["pool_images"]),
                            int(traffic["prompt_tokens"]), sut.vocab)
     lo, hi = int(traffic["rows_min"]), int(traffic["rows_max"])
+    sut.taps.armed = True
     for rows in range(lo, hi + 1):
         sut.submit(sut.tenant_names[rows % len(sut.tenant_names)],
                    sut.pool[:rows])
     while sut.pending:
         sut.poll(block=True)
+    sut.taps.armed = False
     sut.drain_qos()
     sut.hops_at_start = sut.hop_counts()
 

@@ -25,17 +25,17 @@ def _layer_weight_bytes(cfg: ArchConfig) -> int:
 
 
 def arch_block_graph(cfg: ArchConfig, seq: int, *, train: bool = False,
-                     per_sample: bool = True,
-                     prefill: bool = False) -> BlockGraph:
+                     per_sample: bool = True) -> BlockGraph:
     """Per-sample block graph at sequence length ``seq``.
 
     Blocks: [embed] + n_layers × [layer] + [head], as ``LMChain`` runs
-    them.  ``prefill``: the head computes the last position's logits
-    only, and emits them in float32.  For the hybrid family a cut carries
-    the residual stream and the original embeddings, and each layer that
-    applies a memory block charges that application's work, holds its
-    adapter and linear, and names the block's ``shared_group`` (its
-    weights held once per stage).
+    them.  Inference (``train`` False) is a prefill: the head computes
+    the last position's logits only, in float32, as ``forward_prefill``
+    and ``LMChain`` do; training charges every position's.  For the
+    hybrid family a cut carries the residual stream and the original
+    embeddings, and each layer that applies a memory block charges that
+    application's work, holds its adapter and linear, and names the
+    block's ``shared_group`` (its weights held once per stage).
     """
     ctx = (seq + cfg.attn_chunk) / 2 if seq > cfg.attn_chunk else (seq + 1) / 2
     act = seq * cfg.d_model * 2              # bf16 inter-layer activation
@@ -65,15 +65,14 @@ def arch_block_graph(cfg: ArchConfig, seq: int, *, train: bool = False,
             shared_bytes=shared_w if hyb else 0))
     head_w = (0 if cfg.tie_embeddings else cfg.d_model * cfg.vocab * 2) \
         + (cfg.d_model * 2 if cfg.family == "hybrid" else 0)
-    positions = 1 if prefill else seq
+    positions = seq if train else 1
+    out = seq * 4 if train else cfg.vocab * 4
     blocks.append(Block("head",
                         flops=_logit_flops(cfg, positions) * (3 if train else 1),
-                        weight_bytes=head_w,
-                        out_bytes=cfg.vocab * 4 if prefill else seq * 4,
+                        weight_bytes=head_w, out_bytes=out,
                         act_bytes=positions * cfg.vocab * 4))
     return BlockGraph(name=cfg.name, blocks=tuple(blocks),
-                      input_bytes=seq * 4,
-                      output_bytes=cfg.vocab * 4 if prefill else seq * 4)
+                      input_bytes=seq * 4, output_bytes=out)
 
 
 def choose_pipeline_cuts(cfg: ArchConfig, seq: int, n_pods: int,

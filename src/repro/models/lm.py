@@ -596,18 +596,17 @@ class ChainBlock:
 
 
 class LMChain:
-    """A decoder as the chain of blocks that ``EdgePipeline`` and
-    ``Worker`` cut and run, as they do a ``CNNModel``: ``embed`` (token
-    ids (B, S) → state), one block per layer (state → state) and
-    ``head`` (state → last-position logits (B, V), float32).  The state
-    between blocks is ``{"residual": (B, S, D)}``; the hybrid family adds
-    ``"embed"``, the original embeddings its shared blocks read, so a hop
-    carries both.  Blocks are named as in ``arch_block_graph``."""
-
-    FAMILIES = ("ssm", "hybrid")
+    """A hybrid decoder (Zamba2) as the chain of blocks that
+    ``EdgePipeline`` and ``Worker`` cut and run, as they do a
+    ``CNNModel``: ``embed`` (token ids (B, S) → state), one block per
+    layer (state → state) and ``head`` (state → last-position logits
+    (B, V), float32).  The state between blocks is ``{"residual": (B, S,
+    D), "embed": (B, S, D)}``: the residual stream and the original
+    embeddings the shared blocks read, so a hop carries both.  Blocks
+    are named as in ``arch_block_graph``."""
 
     def __init__(self, cfg):
-        if cfg.family not in self.FAMILIES:
+        if cfg.family != "hybrid":
             raise ValueError(f"no block chain for the {cfg.family!r} family")
         self.cfg = cfg
         self.name = cfg.name
@@ -622,10 +621,8 @@ class LMChain:
         cfg = self.cfg
         at = lambda tree, i: jax.tree.map(lambda w: w[i], tree)
         out: list = [{"table": params["embed"]["table"]}]
-        apps = cfg.attn_apps if cfg.family == "hybrid" else (-1,) * cfg.n_layers
-        shared = ([at(params["shared"], m) for m in range(cfg.n_mem_blocks)]
-                  if cfg.family == "hybrid" else [])
-        for i, a in enumerate(apps):
+        shared = [at(params["shared"], m) for m in range(cfg.n_mem_blocks)]
+        for i, a in enumerate(cfg.attn_apps):
             p = {"layer": at(params["layers"], i)}
             if a >= 0:
                 p["shared"] = shared[a % cfg.n_mem_blocks]
@@ -646,8 +643,7 @@ class LMChain:
 
     def _embed(self, p, tokens):
         x = embed_lookup(p["table"], tokens)
-        return {"residual": x, "embed": x} if self.cfg.family == "hybrid" \
-            else {"residual": x}
+        return {"residual": x, "embed": x}
 
     def _layer(self, p, state):
         cfg, x, t = self.cfg, state["residual"], None

@@ -237,11 +237,10 @@ def _run(chain, ps, x, lo, hi):
     return x
 
 
-@pytest.mark.parametrize("name", ["zamba2-7b", "falcon-mamba-7b"])
+@pytest.mark.parametrize("name", ["zamba2-7b"])
 def test_chain_params_agree_with_the_stacked_model(name):
     """``LMChain.block_params`` of ``build_params``'s stacked weights runs
-    the same model as the scanned forward pass, for each family the
-    chain takes (hybrid, ssm)."""
+    the same model as the scanned forward pass."""
     from repro.models.common import InitBuilder
     cfg = configs.reduced(name)
     params = lm.build_params(cfg, InitBuilder(jax.random.PRNGKey(0),
@@ -252,6 +251,14 @@ def test_chain_params_agree_with_the_stacked_model(name):
     chain = lm.LMChain(cfg)
     got = chain.apply(chain.block_params(params), tokens)
     assert float(jnp.max(jnp.abs(got - full[:, -1]))) < 1e-5
+
+
+@pytest.mark.parametrize("name", ["falcon-mamba-7b", "qwen3-1.7b"])
+def test_chain_takes_only_the_hybrid_family(name):
+    """No serving cell runs another family through the split runtime, so
+    ``LMChain`` refuses it rather than run it untested."""
+    with pytest.raises(ValueError, match="no block chain"):
+        lm.LMChain(configs.reduced(name))
 
 
 def test_prefill_then_decode_matches_full_forward_at_published_shapes():
@@ -290,7 +297,7 @@ def test_block_graph_charges_the_benchmarks_flops():
     c = json.loads((Path(__file__).parents[1] / "chipbench" / "configs"
                     / "zamba2-7b-l24.json").read_text())
     cfg = configs.get("zamba2-7b").replace(n_layers=24)
-    g = arch_block_graph(cfg, 2048, prefill=True)
+    g = arch_block_graph(cfg, 2048)
     assert g.total_flops == pytest.approx(flops.zamba2_prompt_flops(c, 2048),
                                           rel=2e-2)
     assert g.blocks[3].out_bytes == 2 * 2048 * 3584 * 2
@@ -311,7 +318,7 @@ def test_solver_cuts_are_the_cells():
     cell = json.loads((root / "cells" /
                        "zamba2-7b-l24.split3.prompt2k.poisson.json").read_text())
     cfg = configs.get("zamba2-7b").replace(n_layers=24)
-    g = arch_block_graph(cfg, 2048, prefill=True)
+    g = arch_block_graph(cfg, 2048)
     pick = best_throughput(solve(g, chips_linear(3), batch=4))
     assert list(pick.partition) == cell["cuts"]
 
