@@ -22,7 +22,9 @@ not work.
   stage.dispatch       Worker.run: the program launch, with the upload
                        of a host input
   stage.sync_wait      Worker.run: blocked until the device is done
-  hop.d2h              EmulatedChannel: the copy off the device
+  hop.d2h              EmulatedChannel: the copy off the device, coded
+                       hops only (an uncoded tensor crosses as the
+                       device array)
   hop.encode           EmulatedChannel: the codec's packing
   hop.decode           EmulatedChannel: the codec's unpacking
   hop.put_wait         EmulatedChannel.send: blocked on a full hop queue

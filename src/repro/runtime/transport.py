@@ -43,6 +43,7 @@ replayable ``LinkTrace``, so real runs can seed the emulator.
 """
 from __future__ import annotations
 
+import math
 import os
 import pickle
 import queue
@@ -312,6 +313,11 @@ def as_jax(x):
     return x
 
 
+def _is_array(x) -> bool:
+    """A tensor, on the host or a device: it has a shape and a dtype."""
+    return hasattr(x, "shape") and hasattr(x, "dtype")
+
+
 # --------------------------------------------------------------------------- #
 # Observation bookkeeping (shared by live channels and orchestrator meters)
 # --------------------------------------------------------------------------- #
@@ -336,16 +342,23 @@ class HopObservations:
         # carries its ``residual`` and ``embed``, a CNN's one
         # ``activation`` (emulated hops; process hops leave it empty)
         self.tensor_bytes: dict[str, int] = {}
+        # lifetime bytes copied off the device to cross: the tensors a
+        # codec packs, the whole message under the rpc framing; 0 where
+        # every tensor crosses as the device array it is (emulated hops;
+        # process hops leave it 0)
+        self.host_bytes: int = 0
 
     def record(self, nbytes: int, elapsed_s: float, t_s: float,
                raw_bytes: int = -1,
-               tensors: tuple[tuple[str, int], ...] = ()) -> TransferRecord:
+               tensors: tuple[tuple[str, int], ...] = (),
+               host_bytes: int = 0) -> TransferRecord:
         rec = TransferRecord(int(nbytes), float(elapsed_s), float(t_s),
                              int(raw_bytes) if raw_bytes >= 0 else int(nbytes))
         with self._lock:
             self.observations.append(rec)
             self.total_bytes += rec.nbytes
             self.total_raw_bytes += rec.raw_bytes
+            self.host_bytes += int(host_bytes)
             for name, n in tensors:
                 self.tensor_bytes[name] = self.tensor_bytes.get(name, 0) + n
             if rec.nbytes > 0:
@@ -471,8 +484,9 @@ class EmulatedChannel(Channel):
     """tc-netem analogue (the former ``EmulatedLink``): sleeps
     RTT/2 + bytes/bw per message, samples ``LinkTrace`` hops at the
     pipeline clock, and hands arrays to the next thread through a
-    bounded queue — zero-copy under the lightweight framing, a full
-    serialize/deserialize round trip under the rpc framing."""
+    bounded queue — zero-copy under the lightweight framing (an uncoded
+    tensor crosses as the device array it is, with no host copy), a
+    full serialize/deserialize round trip under the rpc framing."""
 
     measured = False
 
@@ -485,7 +499,8 @@ class EmulatedChannel(Channel):
         self._q: queue.Queue = queue.Queue(maxsize=max(hop.depth, 1))
 
     def emulate(self, nbytes: int, raw_bytes: int = -1,
-                tensors: tuple[tuple[str, int], ...] = ()) -> float:
+                tensors: tuple[tuple[str, int], ...] = (),
+                host_bytes: int = 0) -> float:
         """Inject the modeled wire delay for ``nbytes`` and record it."""
         t = self._clock()
         if isinstance(self.link, LinkTrace):
@@ -493,40 +508,54 @@ class EmulatedChannel(Channel):
         else:
             dt = self.link.transfer_time(nbytes)
         time.sleep(dt)
-        self.record(nbytes, dt, t, raw_bytes=raw_bytes, tensors=tensors)
+        self.record(nbytes, dt, t, raw_bytes=raw_bytes, tensors=tensors,
+                    host_bytes=host_bytes)
         return dt
+
+    def _coded(self, payload) -> bool:
+        """Whether the hop's codec packs ``payload``: a non-empty tensor
+        of a dtype that a codec other than the identity takes.  Only
+        such a tensor is copied to the host."""
+        codec = self.codec
+        return bool(_is_array(payload) and codec.code
+                    and math.prod(payload.shape)
+                    and codec.supports(np.dtype(payload.dtype)))
 
     def _roundtrip(self, payload):
         """Apply the hop codec's exact wire transform in place of real
         packing: the next stage computes on the degraded tensor, so
-        emulated runs carry the codec's accuracy cost end to end.  A
-        dict payload is a set of named tensors, each crossing on its own.
-        → (wire bytes, raw bytes, decoded payload, bytes by tensor)."""
-        if isinstance(payload, dict):
-            parts = {k: self._roundtrip_one(v, k) for k, v in payload.items()}
-            return (sum(p[0] for p in parts.values()),
-                    sum(p[1] for p in parts.values()),
-                    {k: p[2] for k, p in parts.items()},
-                    tuple((k, p[1]) for k, p in parts.items()))
-        wire, raw, out = self._roundtrip_one(payload, "activation")
-        return wire, raw, out, (("activation", raw),)
+        emulated runs carry the codec's accuracy cost end to end.  An
+        uncoded tensor crosses as the device array it is, its bytes
+        taken from shape and dtype.  A dict payload is a set of named
+        tensors, each crossing on its own.  → (wire bytes, raw bytes,
+        decoded payload, bytes by tensor, bytes copied to the host)."""
+        named = payload if isinstance(payload, dict) else {"activation": payload}
+        parts = {k: self._roundtrip_one(v, k) for k, v in named.items()}
+        out = ({k: p[2] for k, p in parts.items()}
+               if isinstance(payload, dict) else parts["activation"][2])
+        host = sum(p[1] for k, p in parts.items() if self._coded(named[k]))
+        return (sum(p[0] for p in parts.values()),
+                sum(p[1] for p in parts.values()), out,
+                tuple((k, p[1]) for k, p in parts.items()), host)
 
     def _roundtrip_one(self, payload, tensor: str):
         """→ (wire bytes, raw bytes, decoded tensor)."""
+        if _is_array(payload) and not self._coded(payload):
+            raw = math.prod(payload.shape) * np.dtype(payload.dtype).itemsize
+            return raw, raw, payload
         ids = dict(seq=current_seq(), hop=self.hop.index, tensor=tensor,
                    bytes=getattr(payload, "nbytes", -1))
         with TraceAnnotation("hop.d2h", **ids):
             host = np.asarray(payload)
         raw = host.size * host.dtype.itemsize
-        codec = self.codec
-        if not (codec.code and host.size and codec.supports(host.dtype)):
-            return raw, raw, payload
+        if not _is_array(payload):
+            return raw, raw, payload      # a canceled batch's marker
         if not host.flags.c_contiguous:
             host = np.ascontiguousarray(host)
         with TraceAnnotation("hop.encode", **ids):
-            buf = codec.encode(host)
+            buf = self.codec.encode(host)
         with TraceAnnotation("hop.decode", **ids):
-            out = codec.decode(buf, host.shape, host.dtype)
+            out = self.codec.decode(buf, host.shape, host.dtype)
         return len(buf), raw, out
 
     def send(self, payload=None, kind: int = BATCH):
@@ -534,10 +563,12 @@ class EmulatedChannel(Channel):
             tensors: tuple[tuple[str, int], ...] = ()
             if self.hop.framing == "pickle":
                 buf = _Serializer.dumps(payload)
-                nbytes, raw, out = len(buf), len(buf), _Serializer.loads(buf)
+                nbytes = raw = host = len(buf)
+                out = _Serializer.loads(buf)
             else:
-                nbytes, raw, out, tensors = self._roundtrip(payload)
-            dt = self.emulate(nbytes, raw_bytes=raw, tensors=tensors)
+                nbytes, raw, out, tensors, host = self._roundtrip(payload)
+            dt = self.emulate(nbytes, raw_bytes=raw, tensors=tensors,
+                              host_bytes=host)
             with TraceAnnotation("hop.put_wait", seq=current_seq(),
                                  hop=self.hop.index):
                 self._q.put((kind, out))
@@ -547,7 +578,7 @@ class EmulatedChannel(Channel):
                      or hasattr(payload, "dtype"))):
             # round-trip (no delay): warms the codec's jitted kernels and
             # hands downstream a representative degraded exemplar
-            _, _, payload, _ = self._roundtrip(payload)
+            payload = self._roundtrip(payload)[2]
             self._q.put((kind, payload))
             return None
         if kind == PROBE:

@@ -66,9 +66,13 @@ def _host_spans(trace_dir) -> list[tuple[str, dict]]:
     return out
 
 
-def test_every_micro_batch_leaves_its_spans_under_its_seq(tiny, tmp_path):
+@pytest.mark.parametrize("codec", ["none", "int8"])
+def test_every_micro_batch_leaves_its_spans_under_its_seq(tiny, tmp_path,
+                                                           codec):
+    """An uncoded hop copies nothing to the host, so it leaves no
+    ``hop.d2h``; a coded hop copies, packs and unpacks once per batch."""
     m, params = tiny
-    pipe = EdgePipeline(m, params, (1, 3), FREE)
+    pipe = EdgePipeline(m, params, (1, 3), FREE, codec=codec)
     rows = np.asarray(jax.random.normal(jax.random.PRNGKey(1),
                                         (2 * N_REQS, 16, 16, 3)))
     pipe.warmup(rows[:MAX_BATCH])
@@ -104,14 +108,19 @@ def test_every_micro_batch_leaves_its_spans_under_its_seq(tiny, tmp_path):
                 n["gateway.fetch"]) == (1, 1, 1), (s, n)
         assert served[s] == [coalesced[s]], (s, served[s])
         assert n["stage.dispatch"] == n["stage.sync_wait"] == 3, (s, n)
-        assert n["hop.d2h"] == n["hop.put_wait"] == 2, (s, n)
-        assert n["hop.encode"] == n["hop.decode"] == 0, (s, n)
+        copies = 2 if codec != "none" else 0
+        assert n["hop.put_wait"] == 2, (s, n)
+        assert n["hop.d2h"] == n["hop.encode"] == n["hop.decode"] == copies, \
+            (s, n)
         assert n["session.result_wait"] >= 1, (s, n)
         assert where[("stage.dispatch", s)] == stages
         assert where[("stage.sync_wait", s)] == stages
-        assert where[("hop.d2h", s)] == {0, 1}
+        assert where[("hop.put_wait", s)] == {0, 1}
+        for name in ("hop.d2h", "hop.encode", "hop.decode"):
+            assert where[(name, s)] == ({0, 1} if copies else set())
     # no stage or hop span ran outside a micro-batch
-    assert not {k for k in by_seq[-1]} & {"stage.dispatch", "hop.d2h"}
+    assert not {k for k in by_seq[-1]} & {"stage.dispatch", "hop.d2h",
+                                          "hop.put_wait"}
 
 
 def test_seq_is_per_thread():

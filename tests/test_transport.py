@@ -8,22 +8,25 @@ hops are modeled sleeps between threads or real TCP / shared-memory
 channels between OS processes — and the measured records must drive the
 closed adaptive loop to a migration.
 """
+import math
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import Scenario, scenarios
 from repro.core.autosplit import LinkEstimator
+from repro.core.codecs import get_codec
 from repro.core.devices import DURESS, LOOPBACK, DeviceProfile, Link
 from repro.models.cnn import zoo
 from repro.runtime.adaptive import AdaptiveRuntime
 from repro.runtime.edge import EdgePipeline
-from repro.runtime.transport import (BATCH, PROBE, HopSpec, ShmemChannel,
-                                     SocketChannel, TransferRecord,
-                                     TransportError, as_jax, get_transport,
-                                     record_trace)
+from repro.runtime.transport import (BATCH, PROBE, EmulatedChannel, HopSpec,
+                                     ShmemChannel, SocketChannel,
+                                     TransferRecord, TransportError, as_jax,
+                                     get_transport, record_trace)
 
 
 @pytest.fixture(scope="module")
@@ -72,6 +75,53 @@ def test_channel_pickle_framing_roundtrip():
         assert rec.nbytes > x.nbytes                    # pickle framing pays
     finally:
         chan.close()
+
+
+class _CopyCountingArray:
+    """A device array's stand-in: shape, dtype, size and nbytes, and a
+    count of the host copies (``__array__`` calls) made of it."""
+
+    def __init__(self, a: np.ndarray):
+        self._a, self.shape, self.dtype = a, a.shape, a.dtype
+        self.size, self.nbytes = a.size, a.nbytes
+        self.copies = 0
+
+    def __array__(self, dtype=None, copy=None):
+        self.copies += 1
+        return self._a if dtype is None else self._a.astype(dtype)
+
+
+@pytest.mark.parametrize("codec,tensors", [
+    ("none", {"activation": ((2, 8, 8, 4), np.float32)}),
+    ("none", {"residual": ((2, 16, 32), jnp.bfloat16),
+              "embed": ((2, 16, 32), jnp.bfloat16)}),
+    ("int8", {"activation": ((2, 8, 8, 4), np.float32)}),
+], ids=["none", "none-dict", "int8"])
+def test_emulated_hop_copies_to_the_host_only_what_it_codes(codec, tensors):
+    """The identity hop hands each tensor on as it is, with no host copy;
+    a coded hop copies each tensor once to pack it.  The bytes recorded
+    come out the same either way."""
+    rng = np.random.default_rng(0)
+    arrays = {k: _CopyCountingArray(rng.standard_normal(s).astype(dt))
+              for k, (s, dt) in tensors.items()}
+    payload = arrays if len(arrays) > 1 else arrays["activation"]
+    free = Link("free", rtt_s=0.0, bw_bytes_per_s=math.inf)
+    chan = EmulatedChannel(HopSpec(index=0, link=free, codec=codec))
+    rec = chan.send(payload, kind=BATCH)
+    kind, out = chan.recv(timeout=5.0)
+
+    coded = get_codec(codec).code != 0
+    raw = {k: a.nbytes for k, a in arrays.items()}
+    assert {k: a.copies for k, a in arrays.items()} == \
+        {k: int(coded) for k in arrays}
+    assert chan.host_bytes == (sum(raw.values()) if coded else 0)
+    wire = sum(get_codec(codec).wire_bytes(a.size, a.dtype.itemsize)
+               for a in arrays.values())
+    assert (rec.nbytes, rec.raw_bytes) == (wire, sum(raw.values()))
+    assert chan.tensor_bytes == raw
+    outs = out if isinstance(out, dict) else {"activation": out}
+    assert kind == BATCH and outs.keys() == arrays.keys()
+    assert all((outs[k] is a) is not coded for k, a in arrays.items())
 
 
 def test_shmem_slot_growth():
